@@ -670,7 +670,10 @@ def bench_drain(full: bool):
       and a pre-filter that finds nothing skips the program entirely);
     * sharding — a 2-shard ``shard_map`` drain (subprocess with 8 forced
       host devices; the main process keeps its single-device view) must
-      match the unsharded drain's placements decision-for-decision.
+      match the unsharded drain's placements decision-for-decision.  The
+      child runs with ``JAX_PLATFORMS=cpu``: it is a forced-host-device
+      rehearsal, never competes for a chip the parent holds, and its row
+      is a CPU number.
     """
     import subprocess
     import sys as _sys
@@ -764,6 +767,7 @@ print(json.dumps({
 """
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([_sys.executable, "-c", shard_code],
                           capture_output=True, text=True, env=env,
                           timeout=540)
@@ -778,8 +782,9 @@ print(json.dumps({
     _row("drain_host_us", us_h, f"makespan {hres.makespan:.0f}s")
     _row("drain_dispatches_per_drain", 0.0,
          f"{per_drain:.2f} (target 1.0, {adm.stats['drains']} drains)")
-    _row("drain_sharded", shard_out["us_sharded"],
-         f"2-shard shard_map, match={shard_out['match']}, "
+    _row("drain_sharded_cpu", shard_out["us_sharded"],
+         f"CPU, 8 forced host devices: 2-shard shard_map, "
+         f"match={shard_out['match']}, "
          f"{shard_out['placed']} placements, "
          f"unsharded={shard_out['us_unsharded']:.0f}us")
     with open("BENCH_drain.json", "w") as f:
@@ -1209,6 +1214,8 @@ BENCHES = {
 
 
 def main() -> None:
+    from repro import compile_cache
+    compile_cache.setup()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None,

@@ -57,6 +57,7 @@ __all__ = [
     "first_attempt",
     "packed_predict",
     "concat_packed",
+    "resolve_backend",
     "simulate_fleet",
     "simulate_fleet_many",
 ]
@@ -675,6 +676,15 @@ def _as_batch(mems) -> FleetBatch:
     return bucket_traces(mems)
 
 
+def resolve_backend(backend: str) -> str:
+    """The probe backend ``backend="auto"`` stands for: the compiled
+    ``oom_probe`` Mosaic kernel on a TPU (a kernel that fails to compile
+    raises; there is no fallback), the ``jnp`` formulation elsewhere."""
+    if backend != "auto":
+        return backend
+    return "pallas" if jax.default_backend() == "tpu" else "jnp"
+
+
 def simulate_fleet_many(
     jobs: Sequence,
     mems: Union[FleetBatch, PackedTraces, Sequence[np.ndarray]],
@@ -749,8 +759,7 @@ def _simulate_fleet_many_impl(
         if sp[0].shape[0] != B:
             raise ValueError(f"{sp[0].shape[0]} plans vs {B} traces")
         packed_jobs.append(sp)
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
+    backend = resolve_backend(backend)
     mm = jnp.float32(machine_memory)
 
     # Phase A: slice each job's packed plans per bucket, probe everything in

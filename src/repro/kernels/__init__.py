@@ -5,8 +5,10 @@
 * ``wastage``         — KS+ fleet-scale wastage evaluation.
 
 Each kernel ships ``kernel.py`` (pl.pallas_call + BlockSpec VMEM tiling),
-``ops.py`` (jit'd wrapper with CPU interpret-mode fallback) and ``ref.py``
-(pure-jnp oracle used by the allclose test sweeps).
+``ops.py`` (jit'd wrapper) and ``ref.py`` (pure-jnp oracle used by the
+allclose test sweeps).  The ``wastage`` wrappers run compiled unless the
+caller passes ``interpret=True``; the ``flash_attention`` and ``ssd``
+wrappers switch to interpret mode off a TPU.
 """
 
 from repro.kernels.flash_attention.ops import flash_attention

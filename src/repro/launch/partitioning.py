@@ -32,15 +32,12 @@ _state = threading.local()
 def auto_axis_types(n_axes: int) -> Dict[str, tuple]:
     """``axis_types=(AxisType.Auto, ...)`` kwargs for ``jax.make_mesh``.
 
-    ``jax.sharding.AxisType`` only exists on JAX versions with explicit
-    sharding (>= 0.5); earlier releases neither expose it nor accept the
-    ``axis_types`` kwarg, and their meshes are implicitly Auto.  Splat the
-    result (``**auto_axis_types(n)``) so both eras build the same mesh.
+    ``jax.make_mesh`` builds Explicit axes unless told otherwise, and the
+    partitioning rules here rely on ``with_sharding_constraint`` under
+    Auto axes.  Splat the result: ``jax.make_mesh(shape, names,
+    **auto_axis_types(len(names)))``.
     """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def default_rules(mesh: Mesh) -> Dict[str, AxisName]:

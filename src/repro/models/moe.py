@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.launch.partitioning import (
     current_batch_axes,
@@ -273,13 +272,13 @@ def _moe_shardmap(x, router_w, w_gate, w_up, w_down, mesh, *,
     xs = x.reshape(n_shards, (B * S) // n_shards, d)
     Tl = xs.shape[1]
 
-    disp = shard_map(
+    disp = jax.shard_map(
         lambda xl, rw: jax.tree.map(
             lambda a: a[None], _local_dispatch(xl[0], rw, topk, C)),
         mesh=mesh,
         in_specs=(P(batch_axes, None, None), P(None, None)),
         out_specs=P(batch_axes),
-        check_rep=False,
+        check_vma=False,
     )
     buf, slot, rows, gate_sorted, keep, probs, counts = disp(xs, router_w)
     # buf: (n_shards, E, C, d) batch-sharded, replicated over model.
@@ -291,7 +290,7 @@ def _moe_shardmap(x, router_w, w_gate, w_up, w_down, mesh, *,
     out_buf = jnp.einsum("secf,efd->secd", act, w_down.astype(x.dtype))
     out_buf = logical_constraint(out_buf, "batch", None, None, None)
 
-    comb = shard_map(
+    comb = jax.shard_map(
         lambda ob, sl, rw, gs, kp: _local_combine(
             ob[0], sl[0], rw[0], gs[0], kp[0], Tl)[None],
         mesh=mesh,
@@ -299,7 +298,7 @@ def _moe_shardmap(x, router_w, w_gate, w_up, w_down, mesh, *,
                   P(batch_axes, None), P(batch_axes, None),
                   P(batch_axes, None)),
         out_specs=P(batch_axes, None, None),
-        check_rep=False,
+        check_vma=False,
     )
     y = comb(out_buf, slot, rows, gate_sorted, keep)
     y = logical_constraint(y, "batch", None, None)

@@ -29,7 +29,7 @@ recomputation:
     exactly the arithmetic the packed ``ClusterSim`` engine inlines,
   - ``backend="fused"`` — ONE jitted XLA dispatch per refresh computing
     every invalid ``(node, lane)`` entry at once on device-resident
-    float64 state (``jax.experimental.enable_x64`` scopes the 64-bit
+    float64 state (``jax.enable_x64(True)`` scopes the 64-bit
     semantics to these calls).  The packed envelope/need/placement-time
     buffers live on the device and are updated in place through donated
     scatter programs, so the per-event hot path is one fused dispatch
@@ -325,9 +325,12 @@ def _drain_kernel_sharded(masked: bool, select: str, shard: int):
     iteration the global "first fitting (queue-order, node-order) pair"
     is found with two collectives: a vectorized ``psum`` OR-reduction
     over the node axis for per-lane any-fit, then a ``pmin`` min-index
-    reduction for the winning node (for ``select="headroom"``: ``pmax``
-    of the head-room then ``pmin`` of the indices attaining it —
-    first-on-ties, matching ``np.argmax``).  The owning shard
+    reduction for the winning node.  For ``select="headroom"`` each shard
+    contributes its (best head-room, lowest index attaining it) pair
+    through an ``all_gather`` and every shard reduces the gathered pairs
+    identically — first-on-ties, matching ``np.argmax``.  (The TPU
+    lowers float64 all-reduces only for sums, so the head-room maximum
+    cannot be a ``pmax``.)  The owning shard
     scatter-subtracts the placed envelope from its local block; the
     packed placement list is replicated.  One placement per iteration —
     selection is globally ordered, so the single-device batched-prefix
@@ -341,7 +344,6 @@ def _drain_kernel_sharded(masked: bool, select: str, shard: int):
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.asarray(jax.devices()[:shard]), ("nodes",))
@@ -392,10 +394,11 @@ def _drain_kernel_sharded(masked: bool, select: str, shard: int):
             else:
                 minres = resid[:, qsel, :].min(axis=-1)
                 head = jnp.where(colf, minres - peak_q[qsel], -jnp.inf)
-                best = lax.pmax(head.max(), "nodes")
-                nsel = lax.pmin(
-                    jnp.where(colf & (head == best), gidx, big).min(),
-                    "nodes")
+                lbest = head.max()
+                lidx = jnp.where(colf & (head == lbest), gidx, big).min()
+                heads = lax.all_gather(lbest, "nodes")     # (shard,)
+                idxs = lax.all_gather(lidx, "nodes")
+                nsel = jnp.where(heads == heads.max(), idxs, big).min()
             place = ~done
             slot = jnp.where(place, count, Q)
             out_lane = out_lane.at[slot].set(q_idx[qsel], mode="drop")
@@ -421,11 +424,11 @@ def _drain_kernel_sharded(masked: bool, select: str, shard: int):
             cond, body, init)
         return out_lane, out_node, count
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         core, mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P(), P("nodes"), P("nodes"),
                   P("nodes"), P("nodes"), P(), P(), P(), P()),
-        out_specs=(P(), P(), P()), check_rep=False)
+        out_specs=(P(), P(), P()), check_vma=False)
 
     @functools.partial(jax.jit, donate_argnums=(2,))
     def kernel(starts, peaks, admit_t, dur, need, grid, caps, node_valid,
@@ -701,10 +704,10 @@ class AdmissionState:
         """In-place device update of re-planned lanes (donated buffers)."""
         if self.backend == "numpy" or self._dirty_dev:
             return
-        from jax.experimental import enable_x64
+        import jax
         scatter = _scatter_rows_fn()
         record_dispatch("admission.scatter", 3)
-        with enable_x64():
+        with jax.enable_x64(True):
             import jax.numpy as jnp
             rows = jnp.asarray(np.asarray(lanes, np.int32))
             self._dstarts = scatter(self._dstarts, rows,
@@ -717,10 +720,10 @@ class AdmissionState:
     def _push_admit(self, lane: int):
         if self.backend == "numpy" or self._dirty_dev:
             return
-        from jax.experimental import enable_x64
+        import jax
         scatter = _scatter_rows_fn()
         record_dispatch("admission.scatter")
-        with enable_x64():
+        with jax.enable_x64(True):
             import jax.numpy as jnp
             self._dadmit = scatter(
                 self._dadmit, jnp.asarray(np.asarray([lane], np.int32)),
@@ -735,7 +738,6 @@ class AdmissionState:
         whole matrix.
         """
         import jax
-        from jax.experimental import enable_x64
         import jax.numpy as jnp
 
         from repro.core.fleet import pad_lane_axis
@@ -759,7 +761,7 @@ class AdmissionState:
             (np.asarray(lanes, np.int32),), (0,), lo=8, fine=True, sub=sub)
         nq = len(lanes)
         record_dispatch("admission.columns")
-        with enable_x64():
+        with jax.enable_x64(True):
             if self._dirty_dev:
                 self._dev_sync()
             # lint: allow[recompile-hazard] stale-row refreshes are execution-bound by design (see comment above): rows stay exact, only the run axis is padded
@@ -916,7 +918,6 @@ class AdmissionState:
         recomputes exactly what a placement can have changed.
         """
         import jax
-        from jax.experimental import enable_x64
         import jax.numpy as jnp
 
         from repro.core.fleet import pad_lane_axis
@@ -942,7 +943,7 @@ class AdmissionState:
             (0, False), lo=8)
         kernel = (_drain_kernel_sharded(self.use_dur, select, self.shard)
                   if self.shard else _drain_kernel(self.use_dur, select))
-        with enable_x64():
+        with jax.enable_x64(True):
             if self._dirty_dev:
                 self._dev_sync()
             out_lane, out_node, count, admit_new = kernel(

@@ -47,7 +47,7 @@ not the other.
 Fused-admission precision contract: the fused engine keeps the float32
 attempt-#1 probe AND the float64 post-retry probes/wastage of the packed
 engine; its admission residuals run in float64 *on the device*
-(``jax.experimental.enable_x64`` scopes 64-bit semantics to those
+(``jax.enable_x64(True)`` scopes 64-bit semantics to those
 dispatches) with the same elementwise operations as the host path.  The
 only permitted divergence is the summation order over a node's resident
 envelopes (numpy reduces linearly, XLA may tree-reduce) — last-ulp

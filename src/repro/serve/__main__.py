@@ -11,10 +11,13 @@ from __future__ import annotations
 import argparse
 import json
 
-from repro.serve.bench import run_saturation
+from repro import compile_cache
 
 
 def main(argv=None) -> int:
+    compile_cache.setup()
+    from repro.serve.bench import run_saturation
+
     ap = argparse.ArgumentParser(
         "python -m repro.serve",
         description="serve_saturation: multi-tenant micro-batched "

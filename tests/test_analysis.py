@@ -188,10 +188,10 @@ class TestX64Scope:
         active, _ = _lint_src(tmp_path, """
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+
 
 def good():
-    with enable_x64():
+    with jax.enable_x64(True):
         return jnp.zeros(4, jnp.float64)
 
 def bad():

@@ -148,17 +148,17 @@ class TestOneProgramPerDrain:
 
 # ------------------------------------- bounded shapes while frontier wanders
 class TestBoundedShapesUnderWander:
-    # Measured cold on jax 0.4.37 CPU: 14 compiles for the full replay
+    # Measured cold on jax 0.9.0 CPU: 23 compiles for the full replay
     # (drain program per queue bucket + columns + scatter + probe).  The
     # bound is deliberately loose — without pow2/pow4 bucketing the
     # wandering frontier compiles per distinct size and blows through it
     # by an order of magnitude.
     COLD_COMPILE_BUDGET = 40
 
-    def _replay(self, seed):
+    def _replay(self, seed, engine="fused"):
         from repro.workloads import scenarios
         wf = scenarios.get("workload_replay", n_tasks=200, seed=seed)
-        sim = ClusterSim(_nodes(), engine="fused", drain="device")
+        sim = ClusterSim(_nodes(), engine=engine, drain="device")
         return sim.run(wf.to_jobs(under_frac=0.2, seed=seed),
                        RetrySpec("ksplus"))
 
@@ -166,6 +166,14 @@ class TestBoundedShapesUnderWander:
         with dispatch_budget(compiles=self.COLD_COMPILE_BUDGET) as cold:
             self._replay(seed=0)
         assert cold.tag_counts["admission.drain"] > 50  # frontier wandered
+        # The attempt-#1 probe compiles once per trace-length bucket, a
+        # property of the workload and not of the frontier: seed 0 pads
+        # its traces to 256 samples, seed 3 to 128.  The packed engine
+        # runs the same set-up probe but admits on the host, so it warms
+        # seed 3's bucket without touching a drain program.
+        with dispatch_budget() as probe_warmup:
+            self._replay(seed=3, engine="packed")
+        assert probe_warmup.tag_counts["admission.drain"] == 0
         # A different workload, same scenario family: every frontier
         # size lands in an already-compiled bucket.
         with dispatch_budget(compiles=0) as warm:

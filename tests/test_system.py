@@ -85,9 +85,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses, jax
 import repro.launch.mesh as mesh_mod
+from repro.launch.partitioning import auto_axis_types
 mesh_mod.make_production_mesh = lambda multi_pod=False: jax.make_mesh(
     (2, 2, 2) if multi_pod else (4, 2),
-    ("pod", "data", "model") if multi_pod else ("data", "model"))
+    ("pod", "data", "model") if multi_pod else ("data", "model"),
+    **auto_axis_types(3 if multi_pod else 2))
 from repro.configs import get_config
 from repro.launch import dryrun
 dryrun.make_production_mesh = mesh_mod.make_production_mesh
