@@ -1,0 +1,10 @@
+"""Mean host wall time of one admission drain (``admission.drain`` span:
+host preparation, the device program and its one readback), in ms."""
+
+
+def read(ctx):
+    durs = [e["dur"] for e in ctx.get("spans") or []
+            if e["name"] == "admission.drain"]
+    if not durs:
+        return None
+    return sum(durs) / len(durs) / 1e3
