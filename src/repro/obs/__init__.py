@@ -2,7 +2,7 @@
 
 Built on the dispatch-tag seam (:mod:`repro.analysis.contracts`): spans
 absorb ``record_dispatch`` tags and ``jax.monitoring`` compile events,
-the metrics registry collects serve/drain/engine counters, and
+the metrics registry collects the serve counters, and
 :mod:`repro.obs.export` writes Chrome-trace/Perfetto JSON, JSONL logs,
 and Prometheus text.  Everything is off by default; the disabled hot
 path is a single ``trace.enabled`` attribute check and tracing never

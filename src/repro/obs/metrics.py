@@ -4,7 +4,7 @@ histograms, and sim-time-keyed series.
 The registry is deliberately tiny — dict lookups and float adds under
 one lock per metric (Series appends are lock-free: deque.append is
 atomic under the GIL) — because its hot-path callers (the micro-batcher
-flush, the admission drain, the fused event loop) record behind the same
+flush, the prediction server) record behind the same
 ``repro.obs.trace.enabled`` guard the tracer uses: with observability
 off, no metric code runs at all.
 
@@ -16,8 +16,7 @@ Four metric kinds, all label-aware (labels are sorted kwarg tuples):
   cumulative-bucket layout Prometheus expects; no dynamic resizing on
   the hot path);
 * :class:`Series` — bounded ``(t, value)`` append log keyed by *sim
-  time*, for the per-engine-event wastage/utilization/starvation curves
-  the online-selection work (ROADMAP items 2/5) reads back.
+  time*.
 
 :func:`repro.obs.export.prometheus_text` renders the registry in
 Prometheus text exposition format; :meth:`Registry.snapshot` gives the
@@ -160,9 +159,7 @@ class Series(_Metric):
         self._points: deque = deque(maxlen=int(maxlen))
 
     def append(self, t: float, v: float) -> None:
-        # Lock-free: deque.append is atomic under the GIL, and this is
-        # the one metric op hot enough (every fused event batch) for a
-        # lock acquire/release to show up in the tracing-overhead gate.
+        # Lock-free: deque.append is atomic under the GIL.
         self._points.append((float(t), float(v)))
 
     def points(self) -> List[Tuple[float, float]]:

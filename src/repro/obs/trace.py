@@ -21,7 +21,11 @@ Three event sources feed one bounded ring buffer:
 
 * **spans** — :func:`span` context managers on a thread-local stack;
   each close appends one complete ("X") event with its duration and
-  whatever dispatch/compile activity it enclosed;
+  whatever dispatch/compile activity it enclosed.  Each span also
+  enters a ``jax.profiler.TraceAnnotation`` of the same name, tagged
+  with the stat :data:`SPAN_STAT`, so that while a profiler session
+  runs the span lands on the trace's host plane, on the device ops'
+  clock (:mod:`repro.obs.xplane` charges device idle time to it);
 * **dispatch tags** — :func:`enable` installs a hook into
   :func:`repro.analysis.contracts.record_dispatch`, so every
   self-reported device-program launch (``admission.drain``,
@@ -44,7 +48,7 @@ from collections import deque
 from typing import Deque, Dict, List, Optional
 
 __all__ = ["enabled", "enable", "disable", "tracing", "span", "instant",
-           "events", "clear", "Span", "DEFAULT_RING"]
+           "events", "clear", "Span", "DEFAULT_RING", "SPAN_STAT"]
 
 DEFAULT_RING = 65536
 
@@ -56,6 +60,13 @@ _ring: Deque[dict] = deque(maxlen=DEFAULT_RING)
 _tls = threading.local()
 _compile_listener_registered = False
 _epoch_ns = time.perf_counter_ns()  # trace-relative timestamp origin
+
+# Stat that marks a span's profiler annotation as a program span, apart
+# from the annotations JAX and callers write on the same host plane.
+SPAN_STAT = "repro_span"
+# ``jax.profiler.TraceAnnotation``, resolved by the first :func:`enable`
+# so that importing this module does not import jax.
+_annotation = None
 
 
 def _stack() -> list:
@@ -74,7 +85,7 @@ class Span:
     activity.  Appended to the ring as a complete event on exit."""
 
     __slots__ = ("name", "args", "tid", "t0", "dispatches",
-                 "compiles", "compile_us")
+                 "compiles", "compile_us", "_tm")
 
     def __init__(self, name: str, args: Optional[dict]):
         self.name = name
@@ -84,6 +95,7 @@ class Span:
         self.dispatches: Optional[Dict[str, int]] = None
         self.compiles = 0
         self.compile_us = 0.0
+        self._tm = None
 
     def add(self, **args) -> "Span":
         """Attach result-side attributes (e.g. ``placed=n``) post-entry."""
@@ -95,14 +107,22 @@ class Span:
 
     def __enter__(self) -> "Span":
         _stack().append(self)
+        if _annotation is not None:
+            self._tm = _annotation(self.name, **{SPAN_STAT: 1})
+            self._tm.__enter__()
         self.t0 = _now_us()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = _now_us()
+        if self._tm is not None:
+            self._tm.__exit__(None, None, None)
+            self._tm = None
         st = _stack()
         if st and st[-1] is self:
             st.pop()
+        elif self in st:  # drop inner spans an exception left open
+            del st[st.index(self):]
         ev = {"ph": "X", "name": self.name, "ts": self.t0,
               "dur": t1 - self.t0, "tid": self.tid}
         if self.args:
@@ -202,8 +222,11 @@ def _ensure_compile_listener() -> None:
 def enable(ring: Optional[int] = None) -> None:
     """Turn tracing on: install the dispatch hook and the compile
     listener, optionally resizing the ring (which clears it)."""
-    global enabled, _ring
+    global enabled, _ring, _annotation
+    from jax.profiler import TraceAnnotation
+
     from repro.analysis import contracts
+    _annotation = TraceAnnotation
     if ring is not None and ring != _ring.maxlen:
         _ring = deque(maxlen=int(ring))
     contracts._obs_dispatch_hook = _on_dispatch

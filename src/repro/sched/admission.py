@@ -78,7 +78,6 @@ import numpy as np
 
 from repro.analysis.contracts import record_dispatch
 from repro.core.envelope import fits_column
-from repro.obs import metrics as _met
 from repro.obs import trace as _obs
 
 __all__ = ["AdmissionState"]
@@ -826,10 +825,6 @@ class AdmissionState:
                            q=q) as sp:
                 out = self._drain(now, lanes, select)
                 sp.add(placed=len(out))
-                _met.hist("admission.drain.lanes",
-                          buckets=_met.COUNT_BUCKETS).observe(q)
-                _met.hist("admission.drain.placed",
-                          buckets=_met.COUNT_BUCKETS).observe(len(out))
             return out
         return self._drain(now, lanes, select)
 
@@ -916,12 +911,21 @@ class AdmissionState:
         device-side rebuild; the placed nodes' cached True entries are
         invalidated afterwards (monotonic rule) so the next refresh
         recomputes exactly what a placement can have changed.
+
+        Traced, three spans tile the device round trip:
+        ``admission.drain.operands`` (host operand build, padding, the
+        x64 scope, uploads), ``admission.drain.launch`` (the
+        asynchronous kernel enqueue) and ``admission.drain.readback``
+        (leaving the x64 scope and the one batched ``device_get``).
         """
         import jax
         import jax.numpy as jnp
 
         from repro.core.fleet import pad_lane_axis
 
+        obs_enabled = _obs.enabled
+        if obs_enabled:
+            phase = _obs.span("admission.drain.operands").__enter__()
         N = self.N
         npad = 1 << max(N - 1, 0).bit_length()
         if self.shard:
@@ -946,22 +950,32 @@ class AdmissionState:
         with jax.enable_x64(True):
             if self._dirty_dev:
                 self._dev_sync()
+            operands = (jnp.asarray(caps), jnp.asarray(node_valid),
+                        jnp.asarray(run_idx), jnp.asarray(run_valid),
+                        jnp.asarray(q_idx), jnp.asarray(q_valid),
+                        jnp.float64(now), jnp.float64(self.tol))
+            if obs_enabled:
+                phase.__exit__(None, None, None)
+                phase = _obs.span("admission.drain.launch").__enter__()
             out_lane, out_node, count, admit_new = kernel(
                 self._dstarts, self._dpeaks, self._dadmit, self._ddur,
-                self._dneed, self._dgrid,
-                jnp.asarray(caps), jnp.asarray(node_valid),
-                jnp.asarray(run_idx), jnp.asarray(run_valid),
-                jnp.asarray(q_idx), jnp.asarray(q_valid),
-                jnp.float64(now), jnp.float64(self.tol))
+                self._dneed, self._dgrid, *operands)
             self._dadmit = admit_new
-        self.stats["drain_dispatches"] += 1
-        record_dispatch("admission.drain")
+            if obs_enabled:
+                phase.__exit__(None, None, None)
+            # Outside the sub-spans: the tag lands on ``admission.drain``.
+            self.stats["drain_dispatches"] += 1
+            record_dispatch("admission.drain")
+            if obs_enabled:
+                phase = _obs.span("admission.drain.readback").__enter__()
         # The drain's placement decisions must reach the host loop below,
         # so one transfer is irreducible — but it is ONE: fetching the
         # three outputs together replaces the previous int(count) +
         # 2x np.asarray round trips with a single batched device_get.
         # lint: allow[host-sync-in-hot-path] single batched readback per drain; decisions feed host bookkeeping
         out_lane, out_node, n = jax.device_get((out_lane, out_node, count))
+        if obs_enabled:
+            phase.__exit__(None, None, None)
         out_lane = out_lane[:n]
         out_node = out_node[:n]
         placed: List[tuple] = []

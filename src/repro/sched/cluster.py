@@ -111,7 +111,6 @@ import numpy as np
 
 from repro.analysis.contracts import record_dispatch
 from repro.core import AllocationPlan, alloc_at, first_violation
-from repro.obs import metrics as _met
 from repro.obs import trace as _obs
 from repro.core.envelope import (
     PAD_START,
@@ -1284,6 +1283,52 @@ class ClusterSim:
                 adm.place(ni, ji, now)
                 place_record(now, ni, ji)
 
+        def stage_retries(retry_set: List[int]):
+            """Compacted OOM re-plan of a run's retrying lanes: one
+            multi-row ``retry_packed``, one batched ``need``/``bounds``
+            refresh and one float64 re-probe per dt group."""
+            rows = np.asarray(retry_set)
+            if spec is not None:
+                ns, npk = retry_packed(
+                    spec, starts[rows], peaks[rows], nseg[rows],
+                    viol[rows] * dts[rows],
+                    np.asarray([float(jobs[ji].mem[viol[ji]])
+                                for ji in retry_set]),
+                    machine_memory=cap_max,
+                    bump=(None if bump_lanes is None
+                          else bump_lanes[rows]))
+                starts[rows], peaks[rows] = ns, npk
+            else:
+                for ji in retry_set:
+                    s, p = PackedEnvelopes(starts, peaks, nseg).row(ji)
+                    new = retry_fn(AllocationPlan(s, p),
+                                   float(viol[ji] * dts[ji]),
+                                   float(jobs[ji].mem[viol[ji]]))
+                    starts[ji, :new.n] = new.starts
+                    starts[ji, new.n:] = PAD_START
+                    peaks[ji, :new.n] = new.peaks
+                    peaks[ji, new.n:] = new.peaks[-1]
+                    nseg[ji] = new.n
+            # Refresh derived state for all retried lanes at once;
+            # post-retry probes stay float64 (precision contract), one
+            # batched pass per dt group.
+            need[rows] = alloc_at_packed(
+                starts[rows], peaks[rows], grid_rel[rows])
+            need_max[rows] = need[rows].max(axis=1)
+            bounds[rows] = segment_sample_bounds(
+                starts[rows], dts[rows][:, None])
+            by_dt: Dict[float, List[int]] = {}
+            for ji in retry_set:
+                by_dt.setdefault(float(dts[ji]), []).append(ji)
+            for dtv, lanes in by_dt.items():
+                g = np.asarray(lanes)
+                tmax = int(lengths[g].max())
+                mems = np.zeros((len(lanes), tmax), np.float64)
+                for r, ji in enumerate(lanes):
+                    mems[r, :lengths[ji]] = jobs[ji].mem
+                viol[g] = first_violation_packed(
+                    starts[g], peaks[g], mems, lengths[g], dtv)
+
         def process_job_run(run_events):
             """One contiguous run of *fresh* done/oom events inside a
             same-time batch: stage wastage and compacted retries exactly
@@ -1317,47 +1362,12 @@ class ClusterSim:
                 if attempts[ji] + 1 < self.max_attempts
                 and peak_demand[ji] <= cap_max]
             if retry_set:
-                rows = np.asarray(retry_set)
-                if spec is not None:
-                    ns, npk = retry_packed(
-                        spec, starts[rows], peaks[rows], nseg[rows],
-                        viol[rows] * dts[rows],
-                        np.asarray([float(jobs[ji].mem[viol[ji]])
-                                    for ji in retry_set]),
-                        machine_memory=cap_max,
-                        bump=(None if bump_lanes is None
-                              else bump_lanes[rows]))
-                    starts[rows], peaks[rows] = ns, npk
+                if _obs.enabled:
+                    with _obs.span("cluster.retry",
+                                   lanes=len(retry_set)):
+                        stage_retries(retry_set)
                 else:
-                    for ji in retry_set:
-                        s, p = PackedEnvelopes(starts, peaks, nseg).row(ji)
-                        new = retry_fn(AllocationPlan(s, p),
-                                       float(viol[ji] * dts[ji]),
-                                       float(jobs[ji].mem[viol[ji]]))
-                        starts[ji, :new.n] = new.starts
-                        starts[ji, new.n:] = PAD_START
-                        peaks[ji, :new.n] = new.peaks
-                        peaks[ji, new.n:] = new.peaks[-1]
-                        nseg[ji] = new.n
-                # Refresh derived state for all retried lanes at once;
-                # post-retry probes stay float64 (precision contract), one
-                # batched pass per dt group.
-                need[rows] = alloc_at_packed(
-                    starts[rows], peaks[rows], grid_rel[rows])
-                need_max[rows] = need[rows].max(axis=1)
-                bounds[rows] = segment_sample_bounds(
-                    starts[rows], dts[rows][:, None])
-                by_dt: Dict[float, List[int]] = {}
-                for ji in retry_set:
-                    by_dt.setdefault(float(dts[ji]), []).append(ji)
-                for dtv, lanes in by_dt.items():
-                    g = np.asarray(lanes)
-                    tmax = int(lengths[g].max())
-                    mems = np.zeros((len(lanes), tmax), np.float64)
-                    for r, ji in enumerate(lanes):
-                        mems[r, :lengths[ji]] = jobs[ji].mem
-                    viol[g] = first_violation_packed(
-                        starts[g], peaks[g], mems, lengths[g], dtv)
+                    stage_retries(retry_set)
                 # NOTE: the admission state keeps each lane's OLD plan
                 # until that lane's kill event is processed below — while
                 # an OOMing job is still resident, the node's residual
@@ -1454,13 +1464,6 @@ class ClusterSim:
                 queue.push_front(parked)
                 parked.clear()
 
-        if _obs.enabled:
-            # Resolve the engine series once — the registry lookup (lock
-            # + dict get) is too costly to repeat on every event batch.
-            _s_wastage = _met.series("cluster.wastage_gbs")
-            _s_util = _met.series("cluster.utilization")
-            _s_starve = _met.series("cluster.starvation_s")
-
         try_admit(0.0)
         guard = 0
         while events:
@@ -1507,15 +1510,6 @@ class ClusterSim:
                     process_join(t, batch[i][3], batch[i][4])
                     i += 1
                     try_admit(t)
-
-            if _obs.enabled:
-                # Per-event-batch engine series keyed by sim time — the
-                # curves ROADMAP items 2/5 (online selection) read back.
-                _s_wastage.append(t, float(wasted.sum()))
-                _s_util.append(t, area_used / max(
-                    cap_integral + cap_sum * (t - cap_last), 1e-9))
-                _s_starve.append(t, starvation_s)
-                _obs.instant("cluster.event_batch", t=t, n=len(batch))
 
         for ji in parked:
             starvation_s += last_t - park_t.pop(ji)

@@ -282,8 +282,9 @@ class TestZeroPerturbation:
         assert not obs.trace.enabled  # trace=True is scoped to the run
         names = {e["name"] for e in obs.events()}
         assert "cluster.run" in names and "admission.drain" in names
-        # The engine series landed, keyed by sim time.
-        assert len(obs.REGISTRY.series("cluster.utilization")) > 0
+        # The drain's round trip and the OOM re-plan are spanned.
+        assert {"admission.drain.operands", "admission.drain.launch",
+                "admission.drain.readback", "cluster.retry"} <= names
 
     def test_traced_run_inside_enabled_scope_not_double_disabled(self):
         jobs = _workload(n_jobs=8)
@@ -312,3 +313,142 @@ class TestZeroPerturbation:
             np.testing.assert_array_equal(a.peaks, b.peaks)
         assert obs.counter("serve.requests").value(
             kind="predict", cache="miss") > 0
+
+
+# ------------------------------------------------- the profiler's clock
+FIXTURE = os.path.join(os.path.dirname(__file__), "..", "bench", "fixtures",
+                       "eager-tiny.xplane.pb.gz")
+DRAIN_PARTS = ("admission.drain.operands", "admission.drain.launch",
+               "admission.drain.readback")
+
+
+@pytest.fixture(scope="module")
+def profiled_replay(tmp_path_factory):
+    """One traced fused churn replay under ``jax.profiler.trace``: the
+    ring's events and the program spans read back from the xplane."""
+    import glob
+
+    import jax
+
+    from repro.obs import xplane
+
+    churn = FaultSchedule.node_churn(_nodes(), rate=1.0 / 120.0,
+                                     horizon=600.0, seed=0, mean_down=60.0)
+    ClusterSim(_nodes(), engine="fused").run(   # compile outside the trace
+        _workload(), RetrySpec("ksplus"), faults=churn)
+    d = str(tmp_path_factory.mktemp("xplane"))
+    obs.clear()
+    with jax.profiler.trace(d):
+        ClusterSim(_nodes(), engine="fused").run(
+            _workload(), RetrySpec("ksplus"), faults=churn, trace=True)
+    ring = [e for e in obs.events() if e["ph"] == "X"]
+    obs.clear()
+    (path,) = glob.glob(os.path.join(d, "**", "*.xplane.pb"),
+                        recursive=True)
+    return ring, xplane.read(xplane.load(path))["spans"]
+
+
+def _inside(inner, outers):
+    s, e = inner
+    return any(a <= s and e <= b for a, b in outers)
+
+
+class TestProfilerClock:
+    def test_every_span_is_on_the_host_plane(self, profiled_replay):
+        from collections import Counter
+
+        ring, host = profiled_replay
+        assert Counter(e["name"] for e in ring) == \
+            Counter(sp[0] for sp in host)
+        drains = [(s, e) for n, s, e, _ in host if n == "admission.drain"]
+        parts = [(s, e) for n, s, e, _ in host if n in DRAIN_PARTS]
+        assert drains and len(parts) == 3 * len(drains)
+        assert all(_inside(p, drains) for p in parts)
+
+    def test_drain_parts_tile_within_their_drain(self, profiled_replay):
+        ring, _ = profiled_replay
+
+        def iv(e):
+            return (e["ts"], e["ts"] + e["dur"])
+        drains = [e for e in ring if e["name"] == "admission.drain"]
+        for d in drains:
+            parts = [e for e in ring if e["name"] in DRAIN_PARTS
+                     and _inside(iv(e), [iv(d)])]
+            assert sorted(e["name"] for e in parts) == sorted(DRAIN_PARTS)
+            assert sum(e["dur"] for e in parts) <= d["dur"]
+            # The dispatch tag stays on the drain, outside its parts.
+            assert d["dispatches"]["admission.drain"] == 1
+            assert not any("admission.drain" in (e.get("dispatches") or {})
+                           for e in parts)
+        runs = [iv(e) for e in ring if e["name"] == "cluster.run"]
+        retries = [iv(e) for e in ring if e["name"] == "cluster.retry"]
+        assert retries and all(_inside(r, runs) for r in retries)
+
+    def test_span_left_open_by_an_exception_is_dropped(self):
+        with obs.tracing():
+            with pytest.raises(RuntimeError):
+                with obs.span("outer"):
+                    obs.span("inner").__enter__()
+                    raise RuntimeError
+            with obs.span("after"):
+                pass
+        assert obs.trace._stack() == []
+        assert [e["name"] for e in obs.events()] == ["outer", "after"]
+
+
+class TestIdleCharging:
+    def test_nested_spans_charge_the_innermost(self):
+        from repro.obs.xplane import charge_idle
+
+        spans = [("run", 0.0, 100.0), ("drain", 10.0, 50.0),
+                 ("drain.readback", 30.0, 50.0)]
+        got = charge_idle([(20.0, 40.0)], spans)
+        assert got == {"drain": 10.0, "drain.readback": 10.0}
+
+    def test_gap_outside_any_span(self):
+        from repro.obs.xplane import OUTSIDE, charge_idle
+
+        got = charge_idle([(0.0, 5.0), (120.0, 130.0)],
+                          [("run", 10.0, 100.0)])
+        assert got == {OUTSIDE: 15.0}
+
+    def test_gap_straddling_two_spans(self):
+        from repro.obs.xplane import OUTSIDE, charge_idle
+
+        spans = [("a", 0.0, 10.0), ("b", 12.0, 20.0)]
+        got = charge_idle([(5.0, 15.0)], spans)
+        assert got == {"a": 5.0, OUTSIDE: 2.0, "b": 3.0}
+        assert sum(got.values()) == 10.0
+
+    def test_gaps_and_self_time(self):
+        from repro.obs.xplane import gaps, span_rows
+
+        assert gaps([(2.0, 4.0), (3.0, 6.0), (8.0, 9.0)], 0.0, 10.0) == \
+            [(0.0, 2.0), (6.0, 8.0), (9.0, 10.0)]
+        rows = span_rows([("run", 0.0, 100.0, 1), ("drain", 10.0, 50.0, 1),
+                          ("drain.launch", 20.0, 25.0, 1),
+                          ("drain", 60.0, 70.0, 1), ("run", 5.0, 8.0, 2)])
+        assert rows["run"] == {"n": 2, "total": 103.0, "self": 53.0}
+        assert rows["drain"] == {"n": 2, "total": 50.0, "self": 45.0}
+        assert rows["drain.launch"]["self"] == 5.0
+
+    def test_summarize_xplane_on_recorded_trace(self, capsys):
+        """The recorded v5e trace holds no program span (only the
+        harness's unmarked ``bench.replay``): all idle time lands
+        outside any span, and the rows add up to the trace's idle."""
+        from repro.obs.xplane import OUTSIDE, load, read
+
+        assert obs_cli(["summarize", "--xplane", FIXTURE]) == 0
+        out = capsys.readouterr().out
+        assert "(no program spans on the host plane)" in out
+        (row,) = [ln for ln in out.splitlines() if ln.startswith(OUTSIDE)]
+        charged = float(row.split()[-1])
+        total = out.splitlines()[-1]
+        assert f"idle {charged:.3f} ms" in total
+        assert f"charged {charged:.3f} ms" in total
+        assert charged > 0
+        assert read(load(FIXTURE))["spans"] == []
+
+    def test_summarize_needs_one_input(self, capsys):
+        with pytest.raises(SystemExit):
+            obs_cli(["summarize"])
