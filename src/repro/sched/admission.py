@@ -162,6 +162,38 @@ def _drain_alloc_chain(rs, rp, relc):
     return alloc
 
 
+def _drain_pack(caps, node_valid, run_idx, run_valid, q_idx, q_valid,
+                now, tol) -> np.ndarray:
+    """The unsharded drain's per-call operands as ONE float64 host vector.
+
+    Layout, in operand order: ``caps (npad) | node_valid (npad) |
+    run_idx (npad*rmax) | run_valid (npad*rmax) | q_idx (Q) | q_valid (Q)
+    | now | tol``; indices and masks are stored as float64, which holds
+    every int32 index and every 0/1 exactly.  :func:`_drain_unpack` is
+    the inverse the program applies.
+    """
+    return np.concatenate(
+        [caps, node_valid, run_idx.reshape(-1), run_valid.reshape(-1),
+         q_idx, q_valid, (now, tol)], dtype=np.float64)
+
+
+def _drain_unpack(packed, npad: int, rmax: int, Q: int):
+    """Slice :func:`_drain_pack`'s vector back into the drain operands
+    ``(caps, node_valid, run_idx, run_valid, q_idx, q_valid, now, tol)``
+    — static slices, an int32 convert for indices, ``!= 0`` for masks.
+    Works on a traced array inside the program and on a numpy array."""
+    nr = npad * rmax
+    o = 0
+    parts = []
+    for n in (npad, npad, nr, nr, Q, Q):
+        parts.append(packed[o:o + n])
+        o += n
+    caps, nv, ri, rv, qi, qv = parts
+    return (caps, nv != 0, ri.astype("int32").reshape(npad, rmax),
+            (rv != 0).reshape(npad, rmax), qi.astype("int32"), qv != 0,
+            packed[o], packed[o + 1])
+
+
 def _drain_kernel(masked: bool, select: str):
     """Build (once) the jitted one-dispatch greedy drain program.
 
@@ -205,6 +237,14 @@ def _drain_kernel(masked: bool, select: str):
     boolean column, identical tie-break to ``np.argmax``) — or
     ``"headroom"`` — most post-placement head-room ``minresid - peak``,
     first on ties (the ElasticPlanner rule).
+
+    Signature: the six device-resident lane buffers (``admit_t``
+    donated) and the packed per-call operands of :func:`_drain_pack`,
+    with the padded node, run and queue widths ``(npad, rmax, Q)`` as
+    static arguments — the bucket triple the shapes already keyed, so
+    the compiled-program count is unchanged.  Returns one int32 vector
+    ``out_lane (Q) | out_node (Q) | count`` and the updated ``admit_t``:
+    one buffer in, one buffer back.
     """
     key = ("drain", masked, select)
     if key in _KERNEL_CACHE:
@@ -213,10 +253,9 @@ def _drain_kernel(masked: bool, select: str):
     import jax.numpy as jnp
     from jax import lax
 
-    @functools.partial(jax.jit, donate_argnums=(2,))
-    def kernel(starts, peaks, admit_t, dur, need, grid,
-               caps, node_valid, run_idx, run_valid,
-               q_idx, q_valid, now, tol):
+    def drain(starts, peaks, admit_t, dur, need, grid,
+              caps, node_valid, run_idx, run_valid,
+              q_idx, q_valid, now, tol):
         N, R = run_idx.shape
         Q = q_idx.shape[0]
         G = grid.shape[1]
@@ -311,6 +350,15 @@ def _drain_kernel(masked: bool, select: str):
         # out-of-range fill B and drop.
         admit_new = admit_t.at[out_lane].set(now, mode="drop")
         return out_lane, out_node, count, admit_new
+
+    @functools.partial(jax.jit, donate_argnums=(2,),
+                       static_argnames=("npad", "rmax", "Q"))
+    def kernel(starts, peaks, admit_t, dur, need, grid, packed, *,
+               npad: int, rmax: int, Q: int):
+        out_lane, out_node, count, admit_new = drain(
+            starts, peaks, admit_t, dur, need, grid,
+            *_drain_unpack(packed, npad, rmax, Q))
+        return jnp.concatenate([out_lane, out_node, count[None]]), admit_new
 
     _KERNEL_CACHE[key] = kernel
     return kernel
@@ -912,11 +960,24 @@ class AdmissionState:
         invalidated afterwards (monotonic rule) so the next refresh
         recomputes exactly what a placement can have changed.
 
+        Unsharded, the drain crosses the host–device boundary once each
+        way: :func:`_drain_pack` writes the per-call operands (``caps``
+        with its ``-1e30`` pad, ``node_valid``, ``run_idx``/``run_valid``
+        flattened, ``q_idx``/``q_valid``, ``now``, ``tol``) into one
+        float64 vector, one ``device_put`` uploads it, the program slices
+        it apart under the static triple ``(npad, rmax, Q)`` — npad pow2,
+        rmax :func:`_pow4`, Q from ``pad_lane_axis`` — and hands back one
+        int32 vector ``out_lane | out_node | count``.  The sharded
+        program keeps one operand per ``shard_map`` input spec (its
+        node-axis operands are sharded, a replicated buffer would force a
+        reshard), so it uploads them one by one.  Every host-to-device
+        buffer counts one ``admission.drain.upload`` dispatch tag.
+
         Traced, three spans tile the device round trip:
-        ``admission.drain.operands`` (host operand build, padding, the
-        x64 scope, uploads), ``admission.drain.launch`` (the
-        asynchronous kernel enqueue) and ``admission.drain.readback``
-        (leaving the x64 scope and the one batched ``device_get``).
+        ``admission.drain.operands`` (host build and one upload, inside
+        the x64 scope), ``admission.drain.launch`` (the asynchronous
+        kernel enqueue) and ``admission.drain.readback`` (leaving the x64
+        scope and the one ``device_get``).
         """
         import jax
         import jax.numpy as jnp
@@ -945,22 +1006,34 @@ class AdmissionState:
         q_idx, q_valid = pad_lane_axis(
             (np.asarray(lanes, np.int32), np.ones(len(lanes), bool)),
             (0, False), lo=8)
-        kernel = (_drain_kernel_sharded(self.use_dur, select, self.shard)
-                  if self.shard else _drain_kernel(self.use_dur, select))
+        Q = int(q_idx.shape[0])
+        operands = (caps, node_valid, run_idx, run_valid, q_idx, q_valid)
         with jax.enable_x64(True):
             if self._dirty_dev:
                 self._dev_sync()
-            operands = (jnp.asarray(caps), jnp.asarray(node_valid),
-                        jnp.asarray(run_idx), jnp.asarray(run_valid),
-                        jnp.asarray(q_idx), jnp.asarray(q_valid),
-                        jnp.float64(now), jnp.float64(self.tol))
+            if self.shard:
+                kernel = _drain_kernel_sharded(self.use_dur, select,
+                                               self.shard)
+                operands = tuple(jnp.asarray(x) for x in operands) + (
+                    jnp.float64(now), jnp.float64(self.tol))
+                record_dispatch("admission.drain.upload", len(operands))
+            else:
+                kernel = _drain_kernel(self.use_dur, select)
+                packed = jax.device_put(
+                    _drain_pack(*operands, now, self.tol))
+                record_dispatch("admission.drain.upload")
             if obs_enabled:
                 phase.__exit__(None, None, None)
                 phase = _obs.span("admission.drain.launch").__enter__()
-            out_lane, out_node, count, admit_new = kernel(
-                self._dstarts, self._dpeaks, self._dadmit, self._ddur,
-                self._dneed, self._dgrid, *operands)
-            self._dadmit = admit_new
+            if self.shard:
+                *out, self._dadmit = kernel(
+                    self._dstarts, self._dpeaks, self._dadmit, self._ddur,
+                    self._dneed, self._dgrid, *operands)
+            else:
+                out, self._dadmit = kernel(
+                    self._dstarts, self._dpeaks, self._dadmit, self._ddur,
+                    self._dneed, self._dgrid, packed,
+                    npad=npad, rmax=rmax, Q=Q)
             if obs_enabled:
                 phase.__exit__(None, None, None)
             # Outside the sub-spans: the tag lands on ``admission.drain``.
@@ -969,13 +1042,16 @@ class AdmissionState:
             if obs_enabled:
                 phase = _obs.span("admission.drain.readback").__enter__()
         # The drain's placement decisions must reach the host loop below,
-        # so one transfer is irreducible — but it is ONE: fetching the
-        # three outputs together replaces the previous int(count) +
-        # 2x np.asarray round trips with a single batched device_get.
+        # so one transfer is irreducible — but it is ONE batched
+        # device_get, of one packed vector on the unsharded path.
         # lint: allow[host-sync-in-hot-path] single batched readback per drain; decisions feed host bookkeeping
-        out_lane, out_node, n = jax.device_get((out_lane, out_node, count))
+        out = jax.device_get(out)
         if obs_enabled:
             phase.__exit__(None, None, None)
+        if self.shard:
+            out_lane, out_node, n = out
+        else:
+            out_lane, out_node, n = out[:Q], out[Q:2 * Q], out[2 * Q]
         out_lane = out_lane[:n]
         out_node = out_node[:n]
         placed: List[tuple] = []

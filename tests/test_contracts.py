@@ -111,6 +111,36 @@ class TestOneProgramPerDrain:
         assert b.tag_counts["admission.drain"] == 3
         assert adm.stats["drain_dispatches"] == adm.stats["drains"] == 3
 
+    def test_one_upload_per_drain_no_scalar_converts(self, monkeypatch):
+        """The drain's per-call operands cross to the device as ONE
+        packed buffer: exactly one ``admission.drain.upload`` per
+        ``drain()`` call on a warm scripted run, and no eager
+        ``convert_element_type`` program (what ``jnp.float64`` scalar
+        operands launch) dispatched or compiled in the scope."""
+        import jax
+        import jax.numpy as jnp
+        from jax._src import dispatch
+
+        self._scripted_drains()  # warm every bucket the script hits
+        prims = []
+        eager = dispatch.xla_primitive_callable
+
+        def spy(prim, **params):
+            prims.append(prim.name)
+            return eager(prim, **params)
+
+        monkeypatch.setattr(dispatch, "xla_primitive_callable", spy)
+        with dispatch_budget(compiles=0) as b:
+            adm = self._scripted_drains()
+        assert b.tag_counts["admission.drain.upload"] == 3
+        assert adm.stats["drains"] == 3
+        assert b.tag_counts["admission.drain"] == 3
+        assert "convert_element_type" not in prims
+        # The spy sees the pattern it guards against.
+        with jax.enable_x64(True):
+            jnp.float64(1.5)
+        assert "convert_element_type" in prims
+
     def test_different_caps_same_program(self):
         """Capacity values are operands, not shapes: once each scripted
         config has warmed its buckets, fresh states under either config

@@ -40,7 +40,8 @@ from repro.sched import (
     Node,
     OffsetCandidate,
 )
-from repro.sched.admission import AdmissionState
+from repro.analysis.contracts import dispatch_budget
+from repro.sched.admission import AdmissionState, _drain_pack, _drain_unpack
 
 from test_admission_fused import (
     _assert_same,
@@ -150,6 +151,61 @@ class TestDrainUnit:
         n = jax.device_count()
         with pytest.raises(ValueError, match="device"):
             AdmissionState([32.0], K=2, G=8, backend="fused", shard=n + 1)
+
+
+# -------------------------------------------------------- packed operands
+class TestPackedOperands:
+    """The unsharded drain carries its per-call operands in ONE float64
+    vector (``_drain_pack``) that the program slices apart
+    (``_drain_unpack``): every field must come back bit for bit."""
+
+    @pytest.mark.parametrize("now,tol", [(0.1 + 0.2, 1e-9),
+                                         (98765.43210987654, 1e-9),
+                                         (0.0, 1e-12)])
+    def test_pack_unpack_bitwise(self, now, tol):
+        import jax
+        rng = np.random.default_rng(31)
+        B, N, npad, rmax, nq, Q = 8192, 5, 8, 16, 37, 64
+        caps = np.full(npad, -1e30)
+        caps[:N] = rng.uniform(16.0, 128.0, N)
+        node_valid = np.arange(npad) < N
+        run_idx = rng.integers(0, B, (npad, rmax)).astype(np.int32)
+        run_idx[0, 0] = B - 1
+        run_valid = rng.uniform(size=(npad, rmax)) < 0.5
+        q_idx = np.zeros(Q, np.int32)
+        q_idx[:nq] = rng.integers(0, B + 1, nq)
+        q_idx[nq - 1] = B
+        q_valid = rng.uniform(size=Q) < 0.5
+        want = (caps, node_valid, run_idx, run_valid, q_idx, q_valid,
+                np.float64(now), np.float64(tol))
+        packed = _drain_pack(*want)
+        assert packed.dtype == np.float64
+        assert packed.shape == (2 * npad + 2 * npad * rmax + 2 * Q + 2,)
+        unpack = jax.jit(_drain_unpack, static_argnums=(1, 2, 3))
+        with jax.enable_x64(True):
+            got = jax.device_get(
+                unpack(jax.device_put(packed), npad, rmax, Q))
+        for w, g in zip(want, got):
+            g = np.asarray(g)
+            assert (g.dtype, g.shape) == (w.dtype, w.shape)
+            assert g.tobytes() == w.tobytes()
+
+    @pytest.mark.parametrize("select", ["first", "headroom"])
+    def test_q256_bucket_matches_numpy_host_drain(self, select):
+        """A queue in the largest bucket the cohort cells reach (Q=256)
+        through the packed program: one upload, one dispatch, and the
+        numpy host drain's placements."""
+        caps = (96.0, 128.0, 64.0, 112.0, 80.0, 128.0, 72.0, 104.0)
+        out = {}
+        for backend in ("numpy", "fused"):
+            adm = _mk_state(backend, caps=caps)
+            lanes = _mk_lanes(adm, np.random.default_rng(17), 200)
+            with dispatch_budget() as b:
+                out[backend] = adm.drain(5.0, lanes, select=select)
+        assert b.tag_counts["admission.drain.upload"] == 1
+        assert adm.stats["drain_dispatches"] == 1
+        assert out["fused"] == out["numpy"]
+        assert 8 < len(out["fused"]) < 200
 
 
 # ----------------------------------------------------------- engine level

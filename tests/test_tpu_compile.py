@@ -115,12 +115,25 @@ def _drain_args(shard_node, rep):
     )
 
 
+def _packed_drain_args(sharding):
+    """The unsharded drain's signature: the six lane buffers and the one
+    packed float64 operand vector (``admission._drain_pack``'s layout)."""
+    n_packed = 2 * N_ADM + 2 * N_ADM * R_ADM + 2 * Q_ADM + 2
+    return _drain_args(sharding, sharding)[:6] + (
+        _spec((n_packed,), jnp.float64, sharding),)
+
+
 @pytest.mark.parametrize("select", ["first", "headroom"])
 def test_drain_compiles(one_chip, monkeypatch, select):
     monkeypatch.setattr(admission, "_KERNEL_CACHE", {})
     with jax.enable_x64(True):
         kernel = admission._drain_kernel(True, select)
-        kernel.lower(*_drain_args(one_chip, one_chip)).compile()
+        compiled = kernel.lower(*_packed_drain_args(one_chip), npad=N_ADM,
+                                rmax=R_ADM, Q=Q_ADM).compile()
+    # One int32 vector back (out_lane | out_node | count) and admit_t.
+    out, admit = compiled.out_info
+    assert (out.shape, out.dtype) == ((2 * Q_ADM + 1,), jnp.int32)
+    assert admit.shape == (B_ADM,)
 
 
 @pytest.mark.parametrize("select", ["first", "headroom"])
