@@ -283,3 +283,46 @@ def diagnostics(c: Cohort, ref: dict) -> dict:
     return {"tasks": len(c.rows), "peak_queue": o.peak_queue,
             "roots_waited": o.roots_waited, "retries": o.retries,
             "unschedulable": o.unschedulable}
+
+
+def _unchanged(run):
+    def fault(self, jobs, retry, *a, **kw):
+        res = run(self, jobs[:1], retry)
+        res.placements, res.retries, res.finished = [], 0, 0
+        res.total_wastage_gbs, res.makespan = 0.0, 0.0
+        return res
+    return fault
+
+
+def _half(run):
+    def fault(self, jobs, retry, *a, **kw):
+        return run(self, jobs[:len(jobs) // 2], retry)
+    return fault
+
+
+def _altered(run):
+    def fault(self, jobs, retry, *a, **kw):
+        res = run(self, jobs, retry)
+        t, nid, jid = res.placements[-1]
+        res.placements[-1] = (t, nid ^ 1, jid)
+        return res
+    return fault
+
+
+def _planted(wrap):
+    def plant(monkeypatch):
+        from repro.sched import ClusterSim
+        monkeypatch.setattr(ClusterSim, "run", wrap(ClusterSim.run))
+    return plant
+
+
+# Faults planted in the timed path, each of which a run has to find not
+# correct (tests/bench): a name and a factory that plants the fault
+# through pytest's ``monkeypatch`` for the rest of the test.  A replay
+# that returns its state unchanged, one that leaves out half of its
+# jobs, and one whose last placement names another node.
+FAULTS = [
+    ("state_unchanged", _planted(_unchanged)),
+    ("half_batch", _planted(_half)),
+    ("answer_altered", _planted(_altered)),
+]

@@ -18,7 +18,6 @@ per seed.
 from __future__ import annotations
 
 import argparse
-import importlib
 import json
 import os
 import sys
@@ -32,7 +31,7 @@ from bench import run  # noqa: E402
 
 def _host_side(args):
     kind_name, c, outcomes, control = args
-    kind = importlib.import_module(f"bench.kinds.{kind_name}")
+    kind = run.load_kind(kind_name)
     ref = kind.reference(c)
     out = {"lower": kind.readings(c, outcomes, ref)}
     if control:
@@ -55,7 +54,7 @@ def main(argv=None) -> int:
 
     bench = run.load_json("BENCHMARK.json")
     w, _, cfg, traffic = run.find_cell(bench, args.workload)
-    kind = importlib.import_module(f"bench.kinds.{traffic['kind']}")
+    kind = run.load_kind(traffic["kind"])
     run.use_cache_dir()
     run.devices_or_exit(int(w["chips"]))
 

@@ -7,9 +7,11 @@ Run from the root of a checkout on a machine with the chips the cell asks
 for.  Everything is found by name in ``BENCHMARK.json``: the cell gives a
 configuration (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<traffic>.json``); the mix's ``kind`` names the code
-that sets it up, runs its window and checks it
-(``bench/kinds/<kind>.py``); each per-layer metric is read by
-``bench/metrics/<metric>.py``.
+that sets it up, runs its window and checks it, with the faults its
+tests plant (``bench/kinds/<kind>.py``); each per-layer metric is read
+by ``bench/metrics/<metric>.py``, whose ``SPANS``, where the metric is
+read from the program's spans, names the spans it needs.  So a cell of
+a new kind, with metrics of its own, comes in as new files and entries.
 
 A run sets up (data from the seed, fits, one warm-up pass over every
 shape the window uses), measures for ``--seconds`` with nothing compiling,
@@ -32,7 +34,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
@@ -88,6 +89,25 @@ def reader(name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def load_kind(name: str):
+    """``bench/kinds/<name>.py``, from this checkout, as the module
+    ``bench.kinds.<name>``: the name by which the processes it spawns
+    unpickle its functions and data.  Loaded once a process; a module of
+    that name from another file is refused, not replaced."""
+    path = os.path.join(ROOT, "bench", "kinds", name + ".py")
+    module = "bench.kinds." + name
+    mod = sys.modules.get(module)
+    if mod is not None:
+        if os.path.realpath(mod.__file__) != os.path.realpath(path):
+            raise RuntimeError(f"bench: {module} is already loaded from "
+                               f"{mod.__file__}, not {path}")
+        return mod
+    spec = importlib.util.spec_from_file_location(module, path)
+    mod = sys.modules[module] = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 class Compiles:
@@ -162,7 +182,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     bench = bench if bench is not None else load_json("BENCHMARK.json")
     w, entry, cfg, traffic = (cell if cell is not None
                               else find_cell(bench, workload))
-    kind = importlib.import_module(f"bench.kinds.{traffic['kind']}")
+    kind = load_kind(traffic["kind"])
     e2e, layer = metrics_of(bench, workload, [kind.END_TO_END])
 
     if not os.path.isdir(os.path.join(ROOT, "src", "repro")):

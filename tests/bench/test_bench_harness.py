@@ -5,6 +5,7 @@ out not correct, and the command refuses to measure without a TPU."""
 from __future__ import annotations
 
 import json
+import math
 import os
 import shutil
 import subprocess
@@ -18,7 +19,6 @@ ROOT = run.ROOT
 BENCH = run.load_json("BENCHMARK.json")
 CELLS = [w["name"] for w in BENCH["workloads"]]
 KIND = {w: run.find_cell(BENCH, w)[3]["kind"] for w in CELLS}
-COHORT = next(w for w in CELLS if KIND[w] == "cohort")
 
 
 def _tiny(workload):
@@ -49,24 +49,40 @@ def test_cell_runs_and_is_correct(workload):
     assert diag["compiles_in_window"] == 0
 
 
-# The CPU has no device plane: only the span readers find something.
-SPAN_METRICS = {"cohort": {"loop_self_pct.replay", "drain_ms.replay"}}
-
-
 @pytest.mark.parametrize("workload", CELLS)
-def test_traced_run_reports_span_metrics(workload):
+def test_traced_run_reports_span_metrics(workload, span_metrics,
+                                         monkeypatch):
+    """Every one of the cell's ``program_span`` metrics is due (the run
+    recorded each span in its reader's ``SPANS``) and reads a finite,
+    positive value, and no other metric reads anything: the CPU's trace
+    has no device plane, so ``device_trace`` metrics stay silent."""
+    from repro.obs import trace as obs
+
+    spans = []
+    events = obs.events
+
+    def kept():
+        got = events()
+        spans.extend(got)
+        return got
+    monkeypatch.setattr(obs, "events", kept)
     res, _ = _run(workload, trace=True)
     assert res["correct"] is True
-    assert set(res["metrics"]) == SPAN_METRICS[KIND[workload]]
-    assert all(0 < m["value"] for m in res["metrics"].values())
     assert res["device"]["window_s"] > 0
+    recorded = {e["name"] for e in spans}
+    expected = span_metrics(run, BENCH, workload)
+    missing = {m: sorted(set(run.reader(m).__globals__["SPANS"]) - recorded)
+               for m in expected}
+    assert not any(missing.values()), missing
+    got = res["metrics"]
+    assert set(got) == expected
+    assert all(math.isfinite(m["value"]) and m["value"] > 0
+               for m in got.values()), got
 
 
 @pytest.mark.parametrize("workload", CELLS)
 def test_control_fails_and_program_passes(workload):
-    import importlib
-
-    kind = importlib.import_module(f"bench.kinds.{KIND[workload]}")
+    kind = run.load_kind(KIND[workload])
     _, _, cfg, traffic = _tiny(workload)
     c = kind.build(dict(cfg, history_per_family=16),
                    dict(traffic, samples=8), 3)
@@ -82,44 +98,17 @@ def test_control_fails_and_program_passes(workload):
     assert any(upper[k] > lim[k] for k in lim)
 
 
-def _cohort_unchanged(orig):
-    def fault(self, jobs, retry, *a, **kw):
-        res = orig(self, jobs[:1], retry)
-        res.placements, res.retries, res.finished = [], 0, 0
-        res.total_wastage_gbs, res.makespan = 0.0, 0.0
-        return res
-    return fault
+# Each kind a cell uses, with the first cell of that kind.
+KINDS = {KIND[w]: w for w in reversed(CELLS)}
+FAULTS = [(k, n, plant) for k in sorted(KINDS)
+          for n, plant in run.load_kind(k).FAULTS]
 
 
-def _cohort_half(orig):
-    def fault(self, jobs, retry, *a, **kw):
-        return orig(self, jobs[:len(jobs) // 2], retry)
-    return fault
-
-
-def _cohort_altered(orig):
-    def fault(self, jobs, retry, *a, **kw):
-        res = orig(self, jobs, retry)
-        t, nid, jid = res.placements[-1]
-        res.placements[-1] = (t, nid ^ 1, jid)
-        return res
-    return fault
-
-
-FAULTS = [
-    ("cohort", "state_unchanged", _cohort_unchanged),
-    ("cohort", "half_batch", _cohort_half),
-    ("cohort", "answer_altered", _cohort_altered),
-]
-
-
-@pytest.mark.parametrize("kind,fault", [(k, f) for k, _, f in FAULTS],
+@pytest.mark.parametrize("kind,plant", [(k, p) for k, _, p in FAULTS],
                          ids=[f"{k}-{n}" for k, n, _ in FAULTS])
-def test_planted_fault_is_not_correct(kind, fault, monkeypatch):
-    from repro.sched import ClusterSim
-
-    monkeypatch.setattr(ClusterSim, "run", fault(ClusterSim.run))
-    res, _ = _run(COHORT, seed=5)
+def test_planted_fault_is_not_correct(kind, plant, monkeypatch):
+    plant(monkeypatch)
+    res, _ = _run(KINDS[kind], seed=5)
     assert res["correct"] is False
 
 

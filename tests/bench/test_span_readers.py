@@ -1,6 +1,6 @@
 """The readers of the drain's split and the loop's retry share, on
-hand-made span lists: their values, and nothing where a span is absent
-or the trace has no device plane."""
+hand-made span lists: their values, the same with or without a device
+plane in the trace, and nothing where a span they need is absent."""
 
 from __future__ import annotations
 
@@ -35,30 +35,23 @@ EXPECTED = {"drain_operands_ms.replay": 1.5,
             "drain_launch_ms.replay": 0.3,
             "drain_readback_ms.replay": 0.9,
             "loop_retry_pct.replay": 2.5}
-NEEDS = {"drain_operands_ms.replay": "admission.drain.operands",
-         "drain_launch_ms.replay": "admission.drain.launch",
-         "drain_readback_ms.replay": "admission.drain.readback",
-         "loop_retry_pct.replay": "cluster.retry"}
+TRACES = {"chip": CHIP, "no-trace": None, "no-device": profile.Trace({})}
 
 
+@pytest.mark.parametrize("trace", TRACES.values(), ids=list(TRACES))
 @pytest.mark.parametrize("metric", sorted(EXPECTED))
-def test_reads_the_spans(metric):
-    got = run.reader(metric)({"spans": SPANS, "trace": CHIP})
+def test_reads_the_spans(metric, trace):
+    got = run.reader(metric)({"spans": SPANS, "trace": trace})
     assert got == pytest.approx(EXPECTED[metric])
 
 
 @pytest.mark.parametrize("metric", sorted(EXPECTED))
 def test_absent_span_reads_nothing(metric):
-    spans = [e for e in SPANS if e["name"] != NEEDS[metric]]
-    assert run.reader(metric)({"spans": spans, "trace": CHIP}) is None
-    assert run.reader(metric)({"spans": [], "trace": CHIP}) is None
-
-
-@pytest.mark.parametrize("metric", sorted(EXPECTED))
-def test_trace_without_device_reads_nothing(metric):
     read = run.reader(metric)
-    assert read({"spans": SPANS, "trace": None}) is None
-    assert read({"spans": SPANS, "trace": profile.Trace({})}) is None
+    for need in read.__globals__["SPANS"]:
+        spans = [e for e in SPANS if e["name"] != need]
+        assert read({"spans": spans, "trace": CHIP}) is None, need
+    assert read({"spans": [], "trace": CHIP}) is None
 
 
 def test_drain_split_is_within_the_drain():
