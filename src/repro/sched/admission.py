@@ -72,6 +72,7 @@ faults (``churn_replay`` benchmark).
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import List, Optional, Sequence
 
 import numpy as np
@@ -118,7 +119,7 @@ def _fused_kernel(masked: bool):
 
     @jax.jit
     def kernel(starts, peaks, admit_t, dur, need, grid,
-               caps, run_idx, run_valid, q_idx, now, tol):
+               caps, run_idx, run_valid, used_now, q_idx, now, tol):
         N, R = run_idx.shape
         K = starts.shape[1]
         G = grid.shape[1]
@@ -142,7 +143,8 @@ def _fused_kernel(masked: bool):
             alloc = jnp.where(active, alloc, 0.0)
         alloc = jnp.where(run_valid.reshape(-1)[:, None], alloc, 0.0)
         usage = alloc.reshape(N, R, -1).sum(axis=1)          # (N, Q*G)
-        resid = (caps[:, None] - usage).reshape(N, -1, G)    # (N, Q, G)
+        resid = _at_now((caps[:, None] - usage).reshape(N, -1, G),
+                        caps - used_now)                     # (N, Q, G)
         fits = jnp.all(need[q_idx][None, :, :] <= resid + tol, axis=-1)
         minresid = jnp.min(resid, axis=-1)
         return fits, minresid
@@ -162,35 +164,48 @@ def _drain_alloc_chain(rs, rp, relc):
     return alloc
 
 
-def _drain_pack(caps, node_valid, run_idx, run_valid, q_idx, q_valid,
-                now, tol) -> np.ndarray:
+def _at_now(resid, resid_now):
+    """Residuals ``(N, Q, G)`` with every queued lane's first grid point,
+    the instant ``now`` itself (``grid[:, 0] == 0``), taken from the
+    host's ``resid_now`` ``(N,)`` where it is not NaN (see
+    :meth:`AdmissionState._used_now`)."""
+    import jax.numpy as jnp
+    host = (jnp.arange(resid.shape[-1]) == 0) & ~jnp.isnan(resid_now)[
+        :, None, None]
+    return jnp.where(host, resid_now[:, None, None], resid)
+
+
+def _drain_pack(caps, node_valid, run_idx, run_valid, used_now, q_idx,
+                q_valid, now, tol) -> np.ndarray:
     """The unsharded drain's per-call operands as ONE float64 host vector.
 
     Layout, in operand order: ``caps (npad) | node_valid (npad) |
-    run_idx (npad*rmax) | run_valid (npad*rmax) | q_idx (Q) | q_valid (Q)
-    | now | tol``; indices and masks are stored as float64, which holds
-    every int32 index and every 0/1 exactly.  :func:`_drain_unpack` is
-    the inverse the program applies.
+    run_idx (npad*rmax) | run_valid (npad*rmax) | used_now (npad) |
+    q_idx (Q) | q_valid (Q) | now | tol``; indices and masks are stored
+    as float64, which holds every int32 index and every 0/1 exactly.
+    :func:`_drain_unpack` is the inverse the program applies.
     """
     return np.concatenate(
         [caps, node_valid, run_idx.reshape(-1), run_valid.reshape(-1),
-         q_idx, q_valid, (now, tol)], dtype=np.float64)
+         used_now, q_idx, q_valid, (now, tol)],
+        dtype=np.float64)
 
 
 def _drain_unpack(packed, npad: int, rmax: int, Q: int):
     """Slice :func:`_drain_pack`'s vector back into the drain operands
-    ``(caps, node_valid, run_idx, run_valid, q_idx, q_valid, now, tol)``
-    — static slices, an int32 convert for indices, ``!= 0`` for masks.
-    Works on a traced array inside the program and on a numpy array."""
+    ``(caps, node_valid, run_idx, run_valid, used_now, q_idx, q_valid,
+    now, tol)`` — static slices, an int32 convert for indices, ``!= 0``
+    for masks.  Works on a traced array inside the program and on a
+    numpy array."""
     nr = npad * rmax
     o = 0
     parts = []
-    for n in (npad, npad, nr, nr, Q, Q):
+    for n in (npad, npad, nr, nr, npad, Q, Q):
         parts.append(packed[o:o + n])
         o += n
-    caps, nv, ri, rv, qi, qv = parts
+    caps, nv, ri, rv, un, qi, qv = parts
     return (caps, nv != 0, ri.astype("int32").reshape(npad, rmax),
-            (rv != 0).reshape(npad, rmax), qi.astype("int32"), qv != 0,
+            (rv != 0).reshape(npad, rmax), un, qi.astype("int32"), qv != 0,
             packed[o], packed[o + 1])
 
 
@@ -254,7 +269,7 @@ def _drain_kernel(masked: bool, select: str):
     from jax import lax
 
     def drain(starts, peaks, admit_t, dur, need, grid,
-              caps, node_valid, run_idx, run_valid,
+              caps, node_valid, run_idx, run_valid, used_now,
               q_idx, q_valid, now, tol):
         N, R = run_idx.shape
         Q = q_idx.shape[0]
@@ -275,7 +290,8 @@ def _drain_kernel(masked: bool, select: str):
             alloc = jnp.where(active0, alloc, 0.0)
         alloc = jnp.where(run_valid.reshape(-1)[:, None], alloc, 0.0)
         usage = alloc.reshape(N, R, -1).sum(axis=1)
-        resid0 = (caps[:, None] - usage).reshape(N, Q, G)
+        resid0 = _at_now((caps[:, None] - usage).reshape(N, Q, G),
+                         caps - used_now)
         need_q = need[q_idx]                          # (Q, G)
         if select == "headroom":
             peak_q = jnp.max(peaks[q_idx], axis=1)    # (Q,)
@@ -396,7 +412,7 @@ def _drain_kernel_sharded(masked: bool, select: str, shard: int):
     mesh = Mesh(np.asarray(jax.devices()[:shard]), ("nodes",))
 
     def core(starts, peaks, admit_t, dur, need, grid, caps, node_valid,
-             run_idx, run_valid, q_idx, q_valid, now, tol):
+             run_idx, run_valid, used_now, q_idx, q_valid, now, tol):
         Nl, R = run_idx.shape
         Q = q_idx.shape[0]
         G = grid.shape[1]
@@ -415,7 +431,8 @@ def _drain_kernel_sharded(masked: bool, select: str, shard: int):
             alloc = jnp.where(active0, alloc, 0.0)
         alloc = jnp.where(run_valid.reshape(-1)[:, None], alloc, 0.0)
         usage = alloc.reshape(Nl, R, -1).sum(axis=1)
-        resid0 = (caps[:, None] - usage).reshape(Nl, Q, G)
+        resid0 = _at_now((caps[:, None] - usage).reshape(Nl, Q, G),
+                         caps - used_now)
         need_q = need[q_idx]
         if select == "headroom":
             peak_q = jnp.max(peaks[q_idx], axis=1)
@@ -474,15 +491,15 @@ def _drain_kernel_sharded(masked: bool, select: str, shard: int):
     smapped = jax.shard_map(
         core, mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P(), P("nodes"), P("nodes"),
-                  P("nodes"), P("nodes"), P(), P(), P(), P()),
+                  P("nodes"), P("nodes"), P("nodes"), P(), P(), P(), P()),
         out_specs=(P(), P(), P()), check_vma=False)
 
     @functools.partial(jax.jit, donate_argnums=(2,))
     def kernel(starts, peaks, admit_t, dur, need, grid, caps, node_valid,
-               run_idx, run_valid, q_idx, q_valid, now, tol):
+               run_idx, run_valid, used_now, q_idx, q_valid, now, tol):
         out_lane, out_node, count = smapped(
             starts, peaks, admit_t, dur, need, grid, caps, node_valid,
-            run_idx, run_valid, q_idx, q_valid, now, tol)
+            run_idx, run_valid, used_now, q_idx, q_valid, now, tol)
         admit_new = admit_t.at[out_lane].set(now, mode="drop")
         return out_lane, out_node, count, admit_new
 
@@ -571,6 +588,9 @@ class AdmissionState:
         self.minresid = np.zeros((N, 0), np.float64)
         self.valid = np.zeros((N, 0), bool)
         self._now: Optional[float] = None
+        # Every admission instant so far a whole number: the device's
+        # own float64 is then exact at ``now`` (see :meth:`_used_now`).
+        self._whole_times = True
         self._dirty_dev = True  # device mirrors need a (re)upload
 
     # ------------------------------------------------------------- lane mgmt
@@ -602,9 +622,16 @@ class AdmissionState:
 
     def add_lanes(self, starts, peaks, need, grid,
                   dur=None) -> np.ndarray:
-        """Append lanes; returns their indices.  New entries are invalid."""
+        """Append lanes; returns their indices.  New entries are invalid.
+        Each lane's ``grid`` (its admission horizon, relative) starts at
+        0: the device programs take that point from the host
+        (:meth:`_used_now`)."""
         starts = np.asarray(starts, np.float64).reshape(-1, self.K)
         n = starts.shape[0]
+        grid = np.asarray(grid, np.float64).reshape(n, self.G)
+        if np.any(grid[:, 0] != 0.0):
+            raise ValueError("admission grids must start at 0 (the "
+                             "admission instant)")
         self.starts = np.concatenate([self.starts, starts])
         self.peaks = np.concatenate(
             [self.peaks, np.asarray(peaks, np.float64).reshape(n, self.K)])
@@ -668,12 +695,14 @@ class AdmissionState:
         if self._now is None or now != self._now:
             self.valid[:] = False
             self._now = float(now)
+            self._whole_times &= self._now.is_integer()
 
     def place(self, ni: int, lane: int, now: float):
         """Resident set grows: only the node's True entries can change
         (residual shrank monotonically), so False entries stay valid."""
         self.running[ni].append(lane)
         self.admit_t[lane] = now
+        self._whole_times &= float(now).is_integer()
         self.valid[ni] &= ~self.fits[ni]
         self._push_admit(lane)
 
@@ -776,6 +805,43 @@ class AdmissionState:
                 self._dadmit, jnp.asarray(np.asarray([lane], np.int32)),
                 jnp.asarray(self.admit_t[lane:lane + 1]))
 
+    def _used_now(self, runs: Sequence[List[int]], now: float,
+                  n: int) -> np.ndarray:
+        """Each node's allocation in use at the instant ``now``: the sum
+        over its residents ``runs[i]`` of their envelopes at ``now - t0``
+        (with ``use_dur``, only inside ``[t0, t0 + dur)``), as ``(n,)``
+        with zeros past ``len(runs)`` — the device programs' arithmetic,
+        done here in the host's IEEE float64.
+
+        The programs evaluate residents at ``now + grid`` on the device.
+        At the first grid point, ``now`` itself, ``now - t0`` often lies
+        exactly on a segment start: KS+ re-timed plans start segments at
+        whole samples, and events fall whole samples after admissions.
+        A TPU's float64 is emulated in about 49 bits, not IEEE: once
+        ``now`` and ``t0`` carry fractions (a node leaves at any instant
+        and its evicted tasks are re-admitted then), it can round such a
+        tie to the other segment than the host loops and the legacy
+        engine do, and admit differently.  Every other grid point lies
+        off the starts by far more than that rounding.  While every
+        instant is a whole number, ``now - t0`` is exact on the device
+        too, and this returns NaN: the programs keep their own value."""
+        if self._whole_times:
+            return np.full(n, np.nan)
+        lens = [len(run) for run in runs]
+        lanes = np.fromiter(itertools.chain.from_iterable(runs), np.int64,
+                            sum(lens))
+        rel = now - self.admit_t[lanes]
+        relc = np.maximum(rel, 0.0)
+        starts, peaks = self.starts[lanes], self.peaks[lanes]
+        alloc = peaks[:, 0]
+        for k in range(1, self.K):
+            alloc = np.where(starts[:, k] <= relc, peaks[:, k], alloc)
+        if self.use_dur:
+            alloc = np.where((rel >= 0.0) & (rel < self.dur[lanes] + 1e-9),
+                             alloc, 0.0)
+        return np.bincount(np.repeat(np.arange(len(runs)), lens),
+                           weights=alloc, minlength=n)
+
     def _refresh_fused(self, nodes: np.ndarray, lanes: np.ndarray,
                        sub: int = 8):
         """One fused XLA dispatch for every invalid (node, lane) entry.
@@ -816,7 +882,9 @@ class AdmissionState:
                 self._dstarts, self._dpeaks, self._dadmit, self._ddur,
                 self._dneed, self._dgrid,
                 jnp.asarray(self.caps[nodes]), jnp.asarray(run_idx),
-                jnp.asarray(run_valid), jnp.asarray(q_idx),
+                jnp.asarray(run_valid),
+                jnp.asarray(self._used_now(sel, self._now, len(nodes))),
+                jnp.asarray(q_idx),
                 jnp.float64(self._now), jnp.float64(self.tol))
         # lint: allow[host-sync-in-hot-path] one batched readback materializes the host fits cache the drain pre-filter reads
         fits_h, minresid_h = jax.device_get((fits, minresid))
@@ -963,8 +1031,9 @@ class AdmissionState:
         Unsharded, the drain crosses the host–device boundary once each
         way: :func:`_drain_pack` writes the per-call operands (``caps``
         with its ``-1e30`` pad, ``node_valid``, ``run_idx``/``run_valid``
-        flattened, ``q_idx``/``q_valid``, ``now``, ``tol``) into one
-        float64 vector, one ``device_put`` uploads it, the program slices
+        flattened, each node's allocation in use at ``now``
+        (:meth:`_used_now`), ``q_idx``/``q_valid``, ``now``, ``tol``) into
+        one float64 vector, one ``device_put`` uploads it, the program slices
         it apart under the static triple ``(npad, rmax, Q)`` — npad pow2,
         rmax :func:`_pow4`, Q from ``pad_lane_axis`` — and hands back one
         int32 vector ``out_lane | out_node | count``.  The sharded
@@ -1007,7 +1076,8 @@ class AdmissionState:
             (np.asarray(lanes, np.int32), np.ones(len(lanes), bool)),
             (0, False), lo=8)
         Q = int(q_idx.shape[0])
-        operands = (caps, node_valid, run_idx, run_valid, q_idx, q_valid)
+        operands = (caps, node_valid, run_idx, run_valid,
+                    self._used_now(self.running, now, npad), q_idx, q_valid)
         with jax.enable_x64(True):
             if self._dirty_dev:
                 self._dev_sync()

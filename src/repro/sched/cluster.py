@@ -1449,6 +1449,8 @@ class ClusterSim:
             queue.push_front(requeue)  # evicted jobs go ahead of waiters
 
         def process_join(t: float, nid: int, fe: FaultEvent):
+            """Node (re)join: a new admission row, last in first-fit order;
+            every parked lane goes back to the front of the queue."""
             nonlocal cap_sum, cap_integral, cap_last, starvation_s
             if nid in active_nids:
                 raise ValueError(
@@ -1503,11 +1505,25 @@ class ClusterSim:
                         queue.append(ji)
                     try_admit(t)
                 elif kind_i == "leave":
-                    process_leave(t, batch[i][3])
+                    nid = batch[i][3]
+                    if _obs.enabled:
+                        with _obs.span("cluster.leave", nid=nid) as sp:
+                            lost0, evicted0 = unschedulable, evictions
+                            process_leave(t, nid)
+                            sp.add(evicted=evictions - evicted0,
+                                   doomed=unschedulable - lost0)
+                    else:
+                        process_leave(t, nid)
                     i += 1
                     try_admit(t)
                 else:  # join
-                    process_join(t, batch[i][3], batch[i][4])
+                    nid = batch[i][3]
+                    if _obs.enabled:
+                        with _obs.span("cluster.join", nid=nid,
+                                       unparked=len(parked)):
+                            process_join(t, nid, batch[i][4])
+                    else:
+                        process_join(t, nid, batch[i][4])
                     i += 1
                     try_admit(t)
 

@@ -172,15 +172,16 @@ class TestPackedOperands:
         run_idx = rng.integers(0, B, (npad, rmax)).astype(np.int32)
         run_idx[0, 0] = B - 1
         run_valid = rng.uniform(size=(npad, rmax)) < 0.5
+        used_now = np.where(node_valid, rng.uniform(0.0, 64.0, npad), 0.0)
         q_idx = np.zeros(Q, np.int32)
         q_idx[:nq] = rng.integers(0, B + 1, nq)
         q_idx[nq - 1] = B
         q_valid = rng.uniform(size=Q) < 0.5
-        want = (caps, node_valid, run_idx, run_valid, q_idx, q_valid,
-                np.float64(now), np.float64(tol))
+        want = (caps, node_valid, run_idx, run_valid, used_now, q_idx,
+                q_valid, np.float64(now), np.float64(tol))
         packed = _drain_pack(*want)
         assert packed.dtype == np.float64
-        assert packed.shape == (2 * npad + 2 * npad * rmax + 2 * Q + 2,)
+        assert packed.shape == (3 * npad + 2 * npad * rmax + 2 * Q + 2,)
         unpack = jax.jit(_drain_unpack, static_argnums=(1, 2, 3))
         with jax.enable_x64(True):
             got = jax.device_get(
@@ -206,6 +207,73 @@ class TestPackedOperands:
         assert adm.stats["drain_dispatches"] == 1
         assert out["fused"] == out["numpy"]
         assert 8 < len(out["fused"]) < 200
+
+
+# ------------------------------------------------ the admission instant
+class TestAdmissionInstantFromHost:
+    """At the first grid point, ``now`` itself, a resident's ``now - t0``
+    can lie exactly on a segment start, and a TPU's emulated float64
+    (about 49 bits) can round it to the other segment than the host.
+    The device programs take that point from the host, so a device copy
+    of ``t0`` a few ulps off, as a TPU holds it, changes no decision."""
+
+    T0, NOW, START = 0.1, 5.1, 5.0
+
+    def _state(self, backend):
+        adm = AdmissionState([10.0], K=2, G=8, backend=backend)
+        grid = np.linspace(0.0, 50.0, 8)[None].repeat(2, axis=0)
+        need = np.ones((2, 8))
+        need[1, 0] = 3.0      # fits at ``now`` only beside segment 0
+        r, q = adm.add_lanes(np.asarray([[0.0, self.START], [0.0, 1e30]]),
+                             np.asarray([[2.0, 8.0], [3.0, 3.0]]), need,
+                             grid, dur=np.asarray([100.0, 100.0]))
+        adm.place(0, int(r), self.T0)
+        return adm, int(r), int(q)
+
+    def _nudged(self, adm, r):
+        """The device's ``t0`` moved 1e-12 across the tie, the other way
+        from where the host's rounding put ``now - t0``."""
+        import jax
+        rel = self.NOW - self.T0
+        t0 = self.T0 + (-1e-12 if rel < self.START else 1e-12)
+        with jax.enable_x64(True):
+            adm._dev_sync()
+            adm._dadmit = adm._dadmit.at[r].set(t0)
+
+    def test_drain_and_columns_follow_the_host(self):
+        host, r, q = self._state("numpy")
+        want = host.drain(self.NOW, [q])
+        want_fits = self._state("numpy")[0].columns(self.NOW, [q])
+        adm, r, q = self._state("fused")
+        self._nudged(adm, r)
+        assert adm.drain(self.NOW, [q]) == want
+        adm, r, q = self._state("fused")
+        self._nudged(adm, r)
+        assert (adm.columns(self.NOW, [q]) == want_fits).all()
+
+    def test_whole_instants_keep_the_device_value(self):
+        """While every instant is a whole number the device's float64 is
+        exact at ``now``, and the host sends nothing to replace it; the
+        first fractional instant turns the host's value on for good."""
+        adm, r, q = self._state("fused")
+        assert not adm._whole_times          # placed at T0 = 0.1
+        whole = AdmissionState([10.0], K=2, G=8, backend="fused")
+        whole.add_lanes(np.asarray([[0.0, 5.0]]), np.asarray([[2.0, 8.0]]),
+                        np.ones((1, 8)), np.linspace(0.0, 50.0, 8)[None],
+                        dur=np.asarray([100.0]))
+        whole.place(0, 0, 1.0)
+        whole.sync_now(6.0)
+        assert np.isnan(whole._used_now(whole.running, 6.0, 2)).all()
+        whole.sync_now(6.5)
+        assert whole._used_now(whole.running, 6.5, 2).tolist() == [8.0, 0.0]
+        whole.sync_now(7.0)
+        assert not whole._whole_times
+
+    def test_grid_must_start_at_the_instant(self):
+        adm = AdmissionState([10.0], K=2, G=4, backend="numpy")
+        with pytest.raises(ValueError, match="start at 0"):
+            adm.add_lanes(np.zeros((1, 2)), np.ones((1, 2)), np.ones((1, 4)),
+                          np.linspace(1.0, 4.0, 4)[None])
 
 
 # ----------------------------------------------------------- engine level

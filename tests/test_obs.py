@@ -286,6 +286,44 @@ class TestZeroPerturbation:
         assert {"admission.drain.operands", "admission.drain.launch",
                 "admission.drain.readback", "cluster.retry"} <= names
 
+    def test_membership_spans_under_churn(self):
+        """One ``cluster.leave`` span per leave and one ``cluster.join``
+        per join, inside ``cluster.run`` and outside the drains that
+        follow them; the leaves' ``evicted`` args add up to the
+        replay's evictions, and tracing changes no decision."""
+        churn = FaultSchedule.node_churn(_nodes(), rate=1.0 / 120.0,
+                                         horizon=600.0, seed=0,
+                                         mean_down=60.0)
+        base = ClusterSim(_nodes(), engine="fused").run(
+            _workload(), RetrySpec("ksplus"), faults=churn)
+        assert obs.events() == []
+        traced = ClusterSim(_nodes(), engine="fused").run(
+            _workload(), RetrySpec("ksplus"), faults=churn, trace=True)
+        assert traced.placements == base.placements
+        assert (traced.evictions, traced.unschedulable, traced.starved,
+                traced.starvation_s, traced.total_wastage_gbs) == (
+            base.evictions, base.unschedulable, base.starved,
+            base.starvation_s, base.total_wastage_gbs)
+        ring = obs.events()
+
+        def iv(e):
+            return (e["ts"], e["ts"] + e["dur"])
+        for kind in ("leave", "join"):
+            got = [e for e in ring if e["name"] == f"cluster.{kind}"]
+            assert [e["args"]["nid"] for e in got] == \
+                [fe.nid for fe in churn if fe.kind == kind]
+        leaves = [e for e in ring if e["name"] == "cluster.leave"]
+        assert sum(e["args"]["evicted"] for e in leaves) == \
+            base.evictions > 0
+        assert sum(e["args"]["doomed"] for e in leaves) <= \
+            base.unschedulable
+        members = [iv(e) for e in ring
+                   if e["name"] in ("cluster.leave", "cluster.join")]
+        runs = [iv(e) for e in ring if e["name"] == "cluster.run"]
+        drains = [iv(e) for e in ring if e["name"] == "admission.drain"]
+        assert all(_inside(m, runs) for m in members)
+        assert not any(_inside(d, members) for d in drains)
+
     def test_traced_run_inside_enabled_scope_not_double_disabled(self):
         jobs = _workload(n_jobs=8)
         with obs.tracing():

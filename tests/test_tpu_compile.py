@@ -110,6 +110,7 @@ def _drain_args(shard_node, rep):
         _spec((N_ADM,), f64, shard_node), _spec((N_ADM,), b, shard_node),
         _spec((N_ADM, R_ADM), i32, shard_node),
         _spec((N_ADM, R_ADM), b, shard_node),
+        _spec((N_ADM,), f64, shard_node),
         _spec((Q_ADM,), i32, rep), _spec((Q_ADM,), b, rep),
         _spec((), f64, rep), _spec((), f64, rep),
     )
@@ -118,7 +119,7 @@ def _drain_args(shard_node, rep):
 def _packed_drain_args(sharding):
     """The unsharded drain's signature: the six lane buffers and the one
     packed float64 operand vector (``admission._drain_pack``'s layout)."""
-    n_packed = 2 * N_ADM + 2 * N_ADM * R_ADM + 2 * Q_ADM + 2
+    n_packed = 3 * N_ADM + 2 * N_ADM * R_ADM + 2 * Q_ADM + 2
     return _drain_args(sharding, sharding)[:6] + (
         _spec((n_packed,), jnp.float64, sharding),)
 
