@@ -175,47 +175,84 @@ def _at_now(resid, resid_now):
     return jnp.where(host, resid_now[:, None, None], resid)
 
 
+# Steps one drain program takes at most: the events of a same-instant run
+# are folded into one call, and a longer run is split into consecutive
+# calls.  A constant of the program, not a shape bucket: a call's real
+# step count is an operand, and a one-event drain is the one-step case.
+RUN_STEPS = 16
+
+
 def _drain_pack(caps, node_valid, run_idx, run_valid, used_now, q_idx,
-                q_valid, now, tol) -> np.ndarray:
+                q_from, leave_node, leave_slot, old_starts, old_peaks,
+                n_steps, now, tol) -> np.ndarray:
     """The unsharded drain's per-call operands as ONE float64 host vector.
 
     Layout, in operand order: ``caps (npad) | node_valid (npad) |
-    run_idx (npad*rmax) | run_valid (npad*rmax) | used_now (npad) |
-    q_idx (Q) | q_valid (Q) | now | tol``; indices and masks are stored
+    run_idx (npad*rmax) | run_valid (npad*rmax) | used_now
+    (RUN_STEPS*npad) | q_idx (Q) | q_from (Q) | leave_node (RUN_STEPS) |
+    leave_slot (RUN_STEPS) | old_starts (RUN_STEPS*K) | old_peaks
+    (RUN_STEPS*K) | n_steps | now | tol``; indices and masks are stored
     as float64, which holds every int32 index and every 0/1 exactly.
     :func:`_drain_unpack` is the inverse the program applies.
     """
     return np.concatenate(
         [caps, node_valid, run_idx.reshape(-1), run_valid.reshape(-1),
-         used_now, q_idx, q_valid, (now, tol)],
+         used_now.reshape(-1), q_idx, q_from, leave_node, leave_slot,
+         old_starts.reshape(-1), old_peaks.reshape(-1), (n_steps, now, tol)],
         dtype=np.float64)
 
 
-def _drain_unpack(packed, npad: int, rmax: int, Q: int):
+def _drain_unpack(packed, npad: int, rmax: int, Q: int, K: int):
     """Slice :func:`_drain_pack`'s vector back into the drain operands
-    ``(caps, node_valid, run_idx, run_valid, used_now, q_idx, q_valid,
-    now, tol)`` — static slices, an int32 convert for indices, ``!= 0``
-    for masks.  Works on a traced array inside the program and on a
-    numpy array."""
+    ``(caps, node_valid, run_idx, run_valid, used_now, q_idx, q_from,
+    leave_node, leave_slot, old_starts, old_peaks, n_steps, now, tol)`` —
+    static slices, an int32 convert for indices, ``!= 0`` for masks.
+    Works on a traced array inside the program and on a numpy array."""
+    S = RUN_STEPS
     nr = npad * rmax
     o = 0
     parts = []
-    for n in (npad, npad, nr, nr, npad, Q, Q):
+    for n in (npad, npad, nr, nr, S * npad, Q, Q, S, S, S * K, S * K):
         parts.append(packed[o:o + n])
         o += n
-    caps, nv, ri, rv, un, qi, qv = parts
-    return (caps, nv != 0, ri.astype("int32").reshape(npad, rmax),
-            (rv != 0).reshape(npad, rmax), un, qi.astype("int32"), qv != 0,
-            packed[o], packed[o + 1])
+    caps, nv, ri, rv, un, qi, qf, ln, ls, ost, opk = parts
+    i32 = "int32"
+    return (caps, nv != 0, ri.astype(i32).reshape(npad, rmax),
+            (rv != 0).reshape(npad, rmax), un.reshape(S, npad),
+            qi.astype(i32), qf.astype(i32), ln.astype(i32), ls.astype(i32),
+            ost.reshape(S, K), opk.reshape(S, K), packed[o].astype(i32),
+            packed[o + 1], packed[o + 2])
 
 
 def _drain_kernel(masked: bool, select: str):
     """Build (once) the jitted one-dispatch greedy drain program.
 
-    A full event's admission — including multi-placement drains — is ONE
-    dispatch: a ``lax.while_loop`` over the device-resident state whose
-    carry holds the residual tensor ``resid[n, q, g]`` and the packed
-    placement list.  Each iteration:
+    The program drains a same-instant *run* of events in order — up to
+    ``RUN_STEPS`` steps in one dispatch; a one-event drain is its
+    one-step case.  Step ``k``:
+
+    1. takes the event's lane off its node (``leave_node[k]``, slot
+       ``leave_slot[k]`` of the pre-run resident table; an out-of-range
+       node is no leave) and recomputes that node's base residual from
+       the residents left — the same masked sum as every other row, not
+       the released envelope added back;
+    2. makes visible the queue lanes with ``q_from <= k`` (the pre-run
+       queue has 0, the lanes a step appends — DAG children, a re-queued
+       retry — that step);
+    3. runs the greedy drain below to exhaustion.
+
+    A resident that leaves at step ``k`` with a staged re-plan holds the
+    re-plan in the device buffers already (its queue lane reads it from
+    step ``k`` on); as a resident it is evaluated with ``old_starts`` /
+    ``old_peaks``, the envelope it was admitted with.  The lanes placed
+    in earlier steps of the call count through the carried ``placed``
+    usage (their admission time is ``now`` exactly), and at grid point 0
+    through the host's ``used_now[k]`` plus their allocation at ``rel =
+    0``, which is exact on any float64.
+
+    The greedy drain of one step is a ``lax.while_loop`` whose carry
+    holds the residual tensor ``resid[n, q, g]`` and the packed placement
+    list.  Each iteration:
 
     1. recomputes ``fits[n, q]`` from the carried residuals (the in-loop
        equivalent of refreshing every invalidated fits entry),
@@ -232,18 +269,17 @@ def _drain_kernel(masked: bool, select: str):
     3. scatter-subtracts each placed lane's windowed envelope from its
        node's residual rows and clears the lane's active bit,
 
-    until no queued lane fits.  The placed lanes' admission times are
-    scatter-written into the donated ``admit_t`` buffer in the same
-    dispatch, so the host does zero follow-up device work per drain.
+    until no visible queued lane fits.  The placed lanes' admission
+    times are scatter-written into the donated ``admit_t`` buffer in the
+    same dispatch, so the host does zero follow-up device work per drain.
 
     Callers shrink the lane axis before dispatching: residual
     monotonicity means a lane that does not fit any node on the *base*
     residuals can never place within the drain, so
-    :meth:`AdmissionState.drain` restricts the dispatch to the lanes the
-    (incrementally refreshed) fits cache marks as fitting somewhere —
-    the while-loop then runs over a handful of candidate lanes instead
-    of the whole queue.  The restriction is exact, not approximate: unfit
-    lanes contribute nothing to the independent-prefix bookkeeping (their
+    :meth:`AdmissionState.drain` restricts a wide queue's dispatch to the
+    lanes the (incrementally refreshed) fits cache marks as fitting
+    somewhere.  The restriction is exact, not approximate: unfit lanes
+    contribute nothing to the independent-prefix bookkeeping (their
     ``onehot``/``conflict`` entries are identically False), so the placed
     set and order are bitwise those of the full-queue program.
 
@@ -258,8 +294,8 @@ def _drain_kernel(masked: bool, select: str):
     with the padded node, run and queue widths ``(npad, rmax, Q)`` as
     static arguments — the bucket triple the shapes already keyed, so
     the compiled-program count is unchanged.  Returns one int32 vector
-    ``out_lane (Q) | out_node (Q) | count`` and the updated ``admit_t``:
-    one buffer in, one buffer back.
+    ``out_lane (Q) | out_node (Q) | out_step (Q) | count`` and the
+    updated ``admit_t``: one buffer in, one buffer back.
     """
     key = ("drain", masked, select)
     if key in _KERNEL_CACHE:
@@ -270,16 +306,20 @@ def _drain_kernel(masked: bool, select: str):
 
     def drain(starts, peaks, admit_t, dur, need, grid,
               caps, node_valid, run_idx, run_valid, used_now,
-              q_idx, q_valid, now, tol):
+              q_idx, q_from, leave_node, leave_slot, old_starts, old_peaks,
+              n_steps, now, tol):
         N, R = run_idx.shape
         Q = q_idx.shape[0]
         G = grid.shape[1]
         B = starts.shape[0]
-        # Base residuals from the current residents — elementwise the
-        # same float64 program as the columns kernel.
+        S = leave_node.shape[0]
+        # Base residuals from the pre-run residents — elementwise the
+        # same float64 program as the columns kernel.  A leaving
+        # resident's slot carries the envelope it was admitted with.
         flat = run_idx.reshape(-1)
-        rs = starts[flat]
-        rp = peaks[flat]
+        left = jnp.where(leave_node < N, leave_node * R + leave_slot, N * R)
+        rs = starts[flat].at[left].set(old_starts, mode="drop")
+        rp = peaks[flat].at[left].set(old_peaks, mode="drop")
         rt0 = admit_t[flat]
         tabs = (now + grid[q_idx]).reshape(-1)        # (Q*G,) absolute
         rel = tabs[None, :] - rt0[:, None]
@@ -289,9 +329,10 @@ def _drain_kernel(masked: bool, select: str):
             active0 = (rel >= 0.0) & (rel < rdur[:, None] + 1e-9)
             alloc = jnp.where(active0, alloc, 0.0)
         alloc = jnp.where(run_valid.reshape(-1)[:, None], alloc, 0.0)
-        usage = alloc.reshape(N, R, -1).sum(axis=1)
-        resid0 = _at_now((caps[:, None] - usage).reshape(N, Q, G),
-                         caps - used_now)
+        alloc = alloc.reshape(N, R, -1)
+        # The step from which each resident slot is gone (S: stays).
+        gone = jnp.full((N * R,), S, jnp.int32).at[left].set(
+            jnp.arange(S, dtype=jnp.int32), mode="drop").reshape(N, R)
         need_q = need[q_idx]                          # (Q, G)
         if select == "headroom":
             peak_q = jnp.max(peaks[q_idx], axis=1)    # (Q,)
@@ -305,76 +346,112 @@ def _drain_kernel(masked: bool, select: str):
         nrange = jnp.arange(N, dtype=jnp.int32)
         qrange = jnp.arange(Q, dtype=jnp.int32)
 
-        def cond(st):
-            return ~st[5]
+        def greedy(k, resid, active, placed, out_lane, out_node, out_step,
+                   count):
+            def cond(st):
+                return ~st[-1]
 
-        def body(st):
-            resid, active, out_lane, out_node, count, _ = st
-            fits = jnp.all(need_q[None, :, :] <= resid + tol, axis=-1)
-            fits = fits & node_valid[:, None] & active[None, :]
-            anyfit = fits.any(axis=0)                 # (Q,)
-            done = ~anyfit.any()
-            if select == "first":
-                node_q = jnp.argmax(fits, axis=0).astype(jnp.int32)
-            else:
-                head = resid.min(axis=-1) - peak_q[None, :]
-                node_q = jnp.argmax(
-                    jnp.where(fits, head, -jnp.inf), axis=0
-                ).astype(jnp.int32)
-            # Order-preserving independent prefix: optimistically every
-            # fitting lane before the first whose fit set touches an
-            # already-used node.  Before that first conflict the
-            # optimistic used-set equals the sequential one, so the cut
-            # point (and every placement before it) is exact.
-            onehot = (nrange[:, None] == node_q[None, :]) & anyfit[None, :]
-            before = (jnp.cumsum(onehot, axis=1, dtype=jnp.int32)
-                      - onehot.astype(jnp.int32)) > 0
-            conflict = anyfit & (fits & before).any(axis=0)
-            first_conf = jnp.where(conflict.any(),
-                                   jnp.argmax(conflict).astype(jnp.int32),
-                                   jnp.int32(Q))
-            place = anyfit & (qrange < first_conf) & ~done
-            pos = count + jnp.cumsum(place, dtype=jnp.int32) - 1
-            slot = jnp.where(place, pos, Q)
-            out_lane = out_lane.at[slot].set(q_idx, mode="drop")
-            out_node = out_node.at[slot].set(node_q, mode="drop")
-            count = count + place.sum(dtype=jnp.int32)
-            # Scatter-subtract the placed envelopes: at most one lane per
-            # node per iteration by construction (a second lane fitting a
-            # used node is past the conflict cut), so a node -> queue-col
-            # scatter is collision-free.
-            col = jnp.full((N,), Q, jnp.int32).at[
-                jnp.where(place, node_q, N)].set(qrange, mode="drop")
-            hasl = col < Q
-            gl = q_idx[jnp.where(hasl, col, 0)]
-            pal = _drain_alloc_chain(
-                starts[gl], peaks[gl],
-                jnp.broadcast_to(prelc[None, :], (N, prelc.shape[0])))
-            if masked:
-                pact = (prel[None, :] >= 0.0) \
-                    & (prel[None, :] < dur[gl][:, None] + 1e-9)
-                pal = jnp.where(pact, pal, 0.0)
-            pal = jnp.where(hasl[:, None], pal, 0.0)
-            resid = resid - pal.reshape(N, Q, G)
-            active = active & ~place
-            return (resid, active, out_lane, out_node, count, done)
+            def body(st):
+                resid, active, placed, out_lane, out_node, out_step, \
+                    count, _ = st
+                fits = jnp.all(need_q[None, :, :] <= resid + tol, axis=-1)
+                fits = fits & node_valid[:, None] & active[None, :]
+                anyfit = fits.any(axis=0)                 # (Q,)
+                done = ~anyfit.any()
+                if select == "first":
+                    node_q = jnp.argmax(fits, axis=0).astype(jnp.int32)
+                else:
+                    head = resid.min(axis=-1) - peak_q[None, :]
+                    node_q = jnp.argmax(
+                        jnp.where(fits, head, -jnp.inf), axis=0
+                    ).astype(jnp.int32)
+                # Order-preserving independent prefix: optimistically
+                # every fitting lane before the first whose fit set
+                # touches an already-used node.  Before that first
+                # conflict the optimistic used-set equals the sequential
+                # one, so the cut point (and every placement before it)
+                # is exact.
+                onehot = (nrange[:, None] == node_q[None, :]) \
+                    & anyfit[None, :]
+                before = (jnp.cumsum(onehot, axis=1, dtype=jnp.int32)
+                          - onehot.astype(jnp.int32)) > 0
+                conflict = anyfit & (fits & before).any(axis=0)
+                first_conf = jnp.where(
+                    conflict.any(), jnp.argmax(conflict).astype(jnp.int32),
+                    jnp.int32(Q))
+                place = anyfit & (qrange < first_conf) & ~done
+                pos = count + jnp.cumsum(place, dtype=jnp.int32) - 1
+                slot = jnp.where(place, pos, Q)
+                out_lane = out_lane.at[slot].set(q_idx, mode="drop")
+                out_node = out_node.at[slot].set(node_q, mode="drop")
+                out_step = out_step.at[slot].set(k, mode="drop")
+                count = count + place.sum(dtype=jnp.int32)
+                # Scatter-subtract the placed envelopes: at most one lane
+                # per node per iteration by construction (a second lane
+                # fitting a used node is past the conflict cut), so a
+                # node -> queue-col scatter is collision-free.
+                col = jnp.full((N,), Q, jnp.int32).at[
+                    jnp.where(place, node_q, N)].set(qrange, mode="drop")
+                hasl = col < Q
+                gl = q_idx[jnp.where(hasl, col, 0)]
+                pal = _drain_alloc_chain(
+                    starts[gl], peaks[gl],
+                    jnp.broadcast_to(prelc[None, :], (N, prelc.shape[0])))
+                if masked:
+                    pact = (prel[None, :] >= 0.0) \
+                        & (prel[None, :] < dur[gl][:, None] + 1e-9)
+                    pal = jnp.where(pact, pal, 0.0)
+                pal = jnp.where(hasl[:, None], pal, 0.0)
+                resid = resid - pal.reshape(N, Q, G)
+                placed = placed + pal
+                active = active & ~place
+                return (resid, active, placed, out_lane, out_node,
+                        out_step, count, done)
 
-        init = (resid0, q_valid, jnp.full((Q,), B, jnp.int32),
-                jnp.zeros((Q,), jnp.int32), jnp.int32(0), jnp.bool_(False))
-        _, _, out_lane, out_node, count, _ = lax.while_loop(cond, body, init)
+            return lax.while_loop(
+                cond, body, (resid, active, placed, out_lane, out_node,
+                             out_step, count, jnp.bool_(False)))[1:7]
+
+        def step(k, st):
+            usage, waiting, placed, out_lane, out_node, out_step, count = st
+            # Step k's leave: recompute the node's row from its residents
+            # left (an out-of-range node is no leave, and drops).
+            n = leave_node[k]
+            row = jnp.where((gone[jnp.minimum(n, N - 1)] > k)[:, None],
+                            alloc[jnp.minimum(n, N - 1)], 0.0).sum(axis=0)
+            usage = usage.at[n].set(row, mode="drop")
+            resid = _at_now(
+                (caps[:, None] - (usage + placed)).reshape(N, Q, G),
+                caps - (used_now[k] + placed[:, 0]))
+            visible = waiting & (q_from <= k)
+            active, placed, out_lane, out_node, out_step, count = greedy(
+                k, resid, visible, placed, out_lane, out_node, out_step,
+                count)
+            waiting = waiting & ~(visible & ~active)
+            return (usage, waiting, placed, out_lane, out_node, out_step,
+                    count)
+
+        init = (alloc.sum(axis=1), q_from < S,
+                jnp.zeros((N, Q * G), alloc.dtype),
+                jnp.full((Q,), B, jnp.int32), jnp.zeros((Q,), jnp.int32),
+                jnp.zeros((Q,), jnp.int32), jnp.int32(0))
+        out = lax.fori_loop(0, n_steps, step, init)
+        out_lane, out_node, out_step, count = out[3:]
         # Same-dispatch admit-time scatter: unused slots keep the
         # out-of-range fill B and drop.
         admit_new = admit_t.at[out_lane].set(now, mode="drop")
-        return out_lane, out_node, count, admit_new
+        return out_lane, out_node, out_step, count, admit_new
 
     @functools.partial(jax.jit, donate_argnums=(2,),
                        static_argnames=("npad", "rmax", "Q"))
     def kernel(starts, peaks, admit_t, dur, need, grid, packed, *,
                npad: int, rmax: int, Q: int):
-        out_lane, out_node, count, admit_new = drain(
+        *out, admit_new = drain(
             starts, peaks, admit_t, dur, need, grid,
-            *_drain_unpack(packed, npad, rmax, Q))
-        return jnp.concatenate([out_lane, out_node, count[None]]), admit_new
+            *_drain_unpack(packed, npad, rmax, Q, starts.shape[1]))
+        out_lane, out_node, out_step, count = out
+        return (jnp.concatenate([out_lane, out_node, out_step, count[None]]),
+                admit_new)
 
     _KERNEL_CACHE[key] = kernel
     return kernel
@@ -567,7 +644,7 @@ class AdmissionState:
                     f"--xla_force_host_platform_device_count={shard} "
                     f"before jax initializes its backend")
         self.shard = shard
-        self.stats = {"drains": 0, "drain_dispatches": 0}
+        self.stats = {"drains": 0, "drain_steps": 0, "drain_dispatches": 0}
         self.backend = backend
         self.use_dur = bool(use_dur)
         self.tol = float(tol)
@@ -806,12 +883,16 @@ class AdmissionState:
                 jnp.asarray(self.admit_t[lane:lane + 1]))
 
     def _used_now(self, runs: Sequence[List[int]], now: float,
-                  n: int) -> np.ndarray:
+                  n: int, gone: Optional[np.ndarray] = None) -> np.ndarray:
         """Each node's allocation in use at the instant ``now``: the sum
         over its residents ``runs[i]`` of their envelopes at ``now - t0``
         (with ``use_dur``, only inside ``[t0, t0 + dur)``), as ``(n,)``
         with zeros past ``len(runs)`` — the device programs' arithmetic,
-        done here in the host's IEEE float64.
+        done here in the host's IEEE float64.  With ``gone`` (per
+        resident, in the order of ``runs``: the step of a run from which
+        it has left) it is ``(RUN_STEPS, n)``, row ``k`` without the
+        residents gone by step ``k`` — each row summed in the same order
+        as the ``(n,)`` form over the residents left.
 
         The programs evaluate residents at ``now + grid`` on the device.
         At the first grid point, ``now`` itself, ``now - t0`` often lies
@@ -826,7 +907,7 @@ class AdmissionState:
         instant is a whole number, ``now - t0`` is exact on the device
         too, and this returns NaN: the programs keep their own value."""
         if self._whole_times:
-            return np.full(n, np.nan)
+            return np.full(n if gone is None else (RUN_STEPS, n), np.nan)
         lens = [len(run) for run in runs]
         lanes = np.fromiter(itertools.chain.from_iterable(runs), np.int64,
                             sum(lens))
@@ -839,8 +920,15 @@ class AdmissionState:
         if self.use_dur:
             alloc = np.where((rel >= 0.0) & (rel < self.dur[lanes] + 1e-9),
                              alloc, 0.0)
-        return np.bincount(np.repeat(np.arange(len(runs)), lens),
-                           weights=alloc, minlength=n)
+        node = np.repeat(np.arange(len(runs)), lens)
+        if gone is None:
+            return np.bincount(node, weights=alloc, minlength=n)
+        k = np.arange(RUN_STEPS)[:, None]
+        return np.bincount(
+            (k * n + node[None, :]).reshape(-1),
+            weights=np.where(gone[None, :] > k, alloc[None, :],
+                             0.0).reshape(-1),
+            minlength=RUN_STEPS * n).reshape(RUN_STEPS, n)
 
     def _refresh_fused(self, nodes: np.ndarray, lanes: np.ndarray,
                        sub: int = 8):
@@ -899,12 +987,13 @@ class AdmissionState:
         decision order.
 
         On the fused backend this is ONE device dispatch for the whole
-        drain — the jitted while-loop program of :func:`_drain_kernel`
-        (node-sharded via :func:`_drain_kernel_sharded` when the state
-        was built with ``shard=``), including the donated-buffer
-        admit-time scatter for every placement.  On the numpy backend it
-        is the host reference loop over :meth:`columns` — the oracle the
-        device program is differentially pinned against.
+        drain — the one-step case of the jitted run program of
+        :func:`_drain_kernel` (node-sharded via
+        :func:`_drain_kernel_sharded` when the state was built with
+        ``shard=``), including the donated-buffer admit-time scatter for
+        every placement.  On the numpy backend it is the host reference
+        loop over :meth:`columns` — the oracle the device program is
+        differentially pinned against.
 
         ``select="first"`` is the ClusterSim rule (first fitting node in
         row order); ``select="headroom"`` is the ElasticPlanner rule
@@ -938,11 +1027,77 @@ class AdmissionState:
         if _obs.enabled:
             q = int(np.asarray(lanes).size)
             with _obs.span("admission.drain", backend=self.backend,
-                           q=q) as sp:
+                           q=q, steps=1) as sp:
                 out = self._drain(now, lanes, select)
                 sp.add(placed=len(out))
             return out
         return self._drain(now, lanes, select)
+
+    def drain_run(self, now: float, lanes: Sequence[int],
+                  events: Sequence[tuple],
+                  select: str = "first") -> List[List[tuple]]:
+        """Greedy drains of a same-instant run of events, in order;
+        returns each event's placements ``[(lane, node_row), ...]`` in
+        decision order.
+
+        ``events`` holds ``(node_row, lane, joins, replan)`` per event:
+        ``lane`` leaves ``node_row``, its staged re-plan ``replan`` —
+        ``(starts, peaks, need)``, or None — takes effect as it leaves
+        (:meth:`update_lane`), and ``joins`` join the back of the queue
+        in order, after ``lanes`` and the earlier events' joins.  Then
+        the event's drain runs over the queue left.
+
+        Placement for placement, this is :meth:`release`,
+        :meth:`update_lane` and :meth:`drain` event by event, skipping
+        the drain of an event whose queue is empty.  On the fused
+        backend, unsharded, a run whose queue stays within ``DRAIN_CAP``
+        is one dispatch per ``RUN_STEPS`` events — one ``admission.drain``
+        span with ``steps``, the events it folds — and the host's only
+        per-event work is the operands.  A wider queue, the numpy
+        backend and the sharded program go event by event.
+        """
+        if select not in ("first", "headroom"):
+            raise ValueError(f"unknown drain select rule: {select!r}")
+        self.sync_now(now)
+        queue = [int(x) for x in np.asarray(lanes, np.int64).reshape(-1)]
+        out: List[List[tuple]] = []
+        for c0 in range(0, len(events), RUN_STEPS):
+            chunk = events[c0:c0 + RUN_STEPS]
+            joined = [int(ji) for ev in chunk for ji in ev[2]]
+            nq = len(queue) + len(joined)
+            if not (self.backend == "fused" and not self.shard and self.N
+                    and 0 < nq <= self.DRAIN_CAP):
+                for ni, lane, joins, replan in chunk:
+                    self._leave(ni, lane, replan)
+                    queue.extend(int(ji) for ji in joins)
+                    got = self.drain(now, queue, select) if (
+                        queue and self.N) else []
+                    out.append(got)
+                    if got:
+                        done = {ji for ji, _ in got}
+                        queue = [ji for ji in queue if ji not in done]
+                continue
+            self.stats["drains"] += 1
+            self.stats["drain_steps"] += len(chunk)
+            if _obs.enabled:
+                with _obs.span("admission.drain", backend=self.backend,
+                               q=nq, steps=len(chunk)) as sp:
+                    placed = self._drain_fused(now, queue, select, chunk)
+                    sp.add(placed=sum(len(p) for p in placed))
+            else:
+                placed = self._drain_fused(now, queue, select, chunk)
+            out.extend(placed)
+            done = {ji for p in placed for ji, _ in p}
+            queue = [ji for ji in queue + joined if ji not in done]
+        return out
+
+    def _leave(self, ni: Optional[int], lane: Optional[int], replan):
+        """A run event's lane leaves its node row, re-planned if staged."""
+        if ni is None:
+            return
+        self.release(ni, lane)
+        if replan is not None:
+            self.update_lane(lane, *replan)
 
     def _drain(self, now: float, lanes: Sequence[int],
                select: str) -> List[tuple]:
@@ -950,26 +1105,28 @@ class AdmissionState:
             raise ValueError(f"unknown drain select rule: {select!r}")
         self.sync_now(now)
         self.stats["drains"] += 1
+        self.stats["drain_steps"] += 1
         lanes = [int(x) for x in np.asarray(lanes, np.int64).reshape(-1)]
         if not lanes or self.N == 0:
             return []
         if self.backend == "numpy":
             return self._drain_host(now, lanes, select)
         if self.shard:
-            return self._drain_fused(now, lanes, select)
+            return self._drain_fused(now, lanes, select)[0]
         placed_all: List[tuple] = []
         remaining = lanes
         while True:
             if len(remaining) <= self.DRAIN_CAP:
                 # Narrow queue: the whole thing is the dispatch.
-                placed_all.extend(self._drain_fused(now, remaining, select))
+                placed_all.extend(
+                    self._drain_fused(now, remaining, select)[0])
                 break
             idx = np.nonzero(
                 self.columns(now, remaining).any(axis=0))[0]
             if idx.size == 0:
                 break
             cand = [remaining[i] for i in idx[:self.DRAIN_CAP]]
-            placed = self._drain_fused(now, cand, select)
+            placed = self._drain_fused(now, cand, select)[0]
             placed_all.extend(placed)
             if idx.size <= self.DRAIN_CAP or not placed:
                 # A single chunk held every candidate — the kernel's own
@@ -1009,9 +1166,14 @@ class AdmissionState:
                 placed.append((lane, ni))
         return placed
 
-    def _drain_fused(self, now: float, lanes: List[int],
-                     select: str) -> List[tuple]:
-        """One-dispatch device drain (see :func:`_drain_kernel`).
+    def _drain_fused(self, now: float, lanes: List[int], select: str,
+                     events: Sequence[tuple] = ((None, None, (), None),)
+                     ) -> List[List[tuple]]:
+        """One-dispatch device drain (see :func:`_drain_kernel`) of the
+        run ``events`` (:meth:`drain_run`'s form, at most ``RUN_STEPS``;
+        the default is one step that nothing leaves) over the queue
+        ``lanes``; returns each event's placements.  The sharded program
+        takes the default alone.
 
         The node axis is padded to a power of two (and to a multiple of
         the shard count when sharding) with ``-1e30`` capacities and a
@@ -1030,15 +1192,20 @@ class AdmissionState:
 
         Unsharded, the drain crosses the host–device boundary once each
         way: :func:`_drain_pack` writes the per-call operands (``caps``
-        with its ``-1e30`` pad, ``node_valid``, ``run_idx``/``run_valid``
-        flattened, each node's allocation in use at ``now``
-        (:meth:`_used_now`), ``q_idx``/``q_valid``, ``now``, ``tol``) into
-        one float64 vector, one ``device_put`` uploads it, the program slices
-        it apart under the static triple ``(npad, rmax, Q)`` — npad pow2,
-        rmax :func:`_pow4`, Q from ``pad_lane_axis`` — and hands back one
-        int32 vector ``out_lane | out_node | count``.  The sharded
-        program keeps one operand per ``shard_map`` input spec (its
-        node-axis operands are sharded, a replicated buffer would force a
+        with its ``-1e30`` pad, ``node_valid``, the pre-run residents
+        ``run_idx``/``run_valid`` flattened, each node's allocation in
+        use at ``now`` per step (:meth:`_used_now`), the queue and the
+        step each lane joins it, each step's leaving (node row, resident
+        slot) with the envelope the lane was admitted with, the step
+        count, ``now``, ``tol``) into one float64 vector, one
+        ``device_put`` uploads it, the program slices it apart under the
+        static triple ``(npad, rmax, Q)`` — npad pow2, rmax
+        :func:`_pow4`, Q from ``pad_lane_axis`` — and hands back one
+        int32 vector ``out_lane | out_node | out_step | count``.  The
+        events' leaves and re-plans reach the host state (and a re-plan
+        the device buffers) before the upload.  The sharded program
+        keeps one operand per ``shard_map`` input spec (its node-axis
+        operands are sharded, a replicated buffer would force a
         reshard), so it uploads them one by one.  Every host-to-device
         buffer counts one ``admission.drain.upload`` dispatch tag.
 
@@ -1061,8 +1228,8 @@ class AdmissionState:
         if self.shard:
             npad = max(npad, self.shard)
             npad = -(-npad // self.shard) * self.shard
-        rmax = max(max((len(r) for r in self.running), default=0), 1)
-        rmax = _pow4(rmax)
+        lens = [len(r) for r in self.running]
+        rmax = _pow4(max(max(lens, default=0), 1))
         run_idx = np.zeros((npad, rmax), np.int32)
         run_valid = np.zeros((npad, rmax), bool)
         for i, run in enumerate(self.running):
@@ -1072,12 +1239,38 @@ class AdmissionState:
         caps[:N] = self.caps
         node_valid = np.zeros((npad,), bool)
         node_valid[:N] = True
-        q_idx, q_valid = pad_lane_axis(
-            (np.asarray(lanes, np.int32), np.ones(len(lanes), bool)),
-            (0, False), lo=8)
+        if self.shard:
+            q_idx, q_valid = pad_lane_axis(
+                (np.asarray(lanes, np.int32), np.ones(len(lanes), bool)),
+                (0, False), lo=8)
+            operands = (caps, node_valid, run_idx, run_valid,
+                        self._used_now(self.running, now, npad), q_idx,
+                        q_valid)
+        else:
+            S = RUN_STEPS
+            leave_node = np.full(S, npad)
+            leave_slot = np.zeros(S)
+            old_starts = np.zeros((S, self.K))
+            old_peaks = np.zeros((S, self.K))
+            gone = np.full(sum(lens), S)    # per resident, node-major
+            first = np.cumsum([0] + lens[:-1])
+            queue, q_from = list(lanes), [0] * len(lanes)
+            for k, (ni, lane, joins, _) in enumerate(events):
+                if ni is not None:
+                    slot = self.running[ni].index(lane)
+                    leave_node[k], leave_slot[k] = ni, slot
+                    old_starts[k] = self.starts[lane]
+                    old_peaks[k] = self.peaks[lane]
+                    gone[first[ni] + slot] = k
+                queue.extend(joins)
+                q_from.extend([k] * len(joins))
+            q_idx, q_from = pad_lane_axis(
+                (np.asarray(queue, np.int32), np.asarray(q_from, np.int32)),
+                (0, S), lo=8)
+            used_now = self._used_now(self.running, now, npad, gone)
+            for ni, lane, _, replan in events:
+                self._leave(ni, lane, replan)
         Q = int(q_idx.shape[0])
-        operands = (caps, node_valid, run_idx, run_valid,
-                    self._used_now(self.running, now, npad), q_idx, q_valid)
         with jax.enable_x64(True):
             if self._dirty_dev:
                 self._dev_sync()
@@ -1089,8 +1282,10 @@ class AdmissionState:
                 record_dispatch("admission.drain.upload", len(operands))
             else:
                 kernel = _drain_kernel(self.use_dur, select)
-                packed = jax.device_put(
-                    _drain_pack(*operands, now, self.tol))
+                packed = jax.device_put(_drain_pack(
+                    caps, node_valid, run_idx, run_valid, used_now, q_idx,
+                    q_from, leave_node, leave_slot, old_starts, old_peaks,
+                    len(events), now, self.tol))
                 record_dispatch("admission.drain.upload")
             if obs_enabled:
                 phase.__exit__(None, None, None)
@@ -1120,12 +1315,13 @@ class AdmissionState:
             phase.__exit__(None, None, None)
         if self.shard:
             out_lane, out_node, n = out
+            out_step = np.zeros(n, np.int32)
         else:
-            out_lane, out_node, n = out[:Q], out[Q:2 * Q], out[2 * Q]
-        out_lane = out_lane[:n]
-        out_node = out_node[:n]
-        placed: List[tuple] = []
-        for lane, ni in zip(out_lane.tolist(), out_node.tolist()):
+            out_lane, out_node, out_step, n = (
+                out[:Q], out[Q:2 * Q], out[2 * Q:3 * Q], out[3 * Q])
+        placed: List[List[tuple]] = [[] for _ in events]
+        for lane, ni, k in zip(out_lane[:n].tolist(), out_node[:n].tolist(),
+                               out_step[:n].tolist()):
             # Host bookkeeping per placement; the device-side admit_t
             # scatter already happened inside the drain dispatch.
             self.running[ni].append(lane)
@@ -1138,5 +1334,5 @@ class AdmissionState:
                 # False entries stay valid; only the Trues must be
                 # recomputed on the next refresh.
                 self.valid[ni] &= ~self.fits[ni]
-            placed.append((lane, ni))
+            placed[k].append((lane, ni))
         return placed

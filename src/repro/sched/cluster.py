@@ -14,7 +14,8 @@ Three engines share the event semantics:
 
 * ``engine="fused"`` (default) — the packed layout below, with the
   per-event hot path moved off the host: the admission check is ONE jitted
-  XLA dispatch per event over every (node, queued job) pair at once
+  XLA dispatch per same-instant run of completions (one per other event)
+  over every (node, queued job) pair at once
   (:class:`repro.sched.admission.AdmissionState` — device-resident packed
   state, donated-buffer updates, and an incremental fits-column
   invalidation mask instead of full per-admission recompute), and OOM
@@ -397,7 +398,8 @@ class ClusterSim:
         self.max_attempts = max_attempts
         self.engine = engine
         # Fused-engine drain mode: "device" folds the whole greedy drain
-        # into one jitted dispatch per event (AdmissionState.drain);
+        # into one jitted dispatch per same-instant run of completions
+        # (AdmissionState.drain_run) and per other event (.drain);
         # "host" keeps the per-placement columns/argmax loop as the
         # decision oracle.  ``shard`` shards the drain's node axis over
         # that many devices (shard_map).  Both are ignored by the packed
@@ -1151,10 +1153,11 @@ class ClusterSim:
         *how* the work is done:
 
         * admission — :class:`repro.sched.admission.AdmissionState`: one
-          jitted float64 dispatch per event over every (node, queued lane)
-          pair, then incremental recomputes of only the invalidated
-          entries after each placement, instead of full per-node numpy
-          columns per admission;
+          jitted float64 dispatch per same-instant run of done/oom events
+          (and per other event) over every (node, queued lane) pair, then
+          incremental recomputes of only the invalidated entries after
+          each placement, instead of full per-node numpy columns per
+          admission;
         * retries — all OOMs that land at the same event time are
           compacted into one multi-row ``retry_packed`` re-plan, one
           batched ``need``/``bounds`` refresh and one batched float64
@@ -1229,6 +1232,21 @@ class ClusterSim:
                                         "oom", active_nids[ni], ji,
                                         int(epoch[ji])))
 
+        def park_misfits(ids: np.ndarray, now: float) -> np.ndarray:
+            """Park the queued lanes of ``ids`` no surviving node could
+            ever fit, in order; returns the rest."""
+            if ids.size:
+                cap_hi = float(adm.caps.max()) if adm.N else 0.0
+                bad = need_max[ids] > cap_hi + 1e-9
+                if bad.any():
+                    drop = ids[bad]
+                    queue.remove_many(drop)
+                    for ji in drop.tolist():
+                        parked.append(ji)
+                        park_t[ji] = now
+                    ids = ids[~bad]
+            return ids
+
         def try_admit(now: float):
             """Greedy drain on the shared fits matrix.
 
@@ -1246,17 +1264,7 @@ class ClusterSim:
             per placement, and is pinned bitwise against the device
             path by the differential suite.
             """
-            ids = queue.ids()
-            if ids.size:  # park jobs no surviving node could ever fit
-                cap_hi = float(adm.caps.max()) if adm.N else 0.0
-                bad = need_max[ids] > cap_hi + 1e-9
-                if bad.any():
-                    drop = ids[bad]
-                    queue.remove_many(drop)
-                    for ji in drop.tolist():
-                        parked.append(ji)
-                        park_t[ji] = now
-                    ids = ids[~bad]
+            ids = park_misfits(queue.ids(), now)
             adm.sync_now(now)
             if device_drain:
                 if ids.size == 0 or adm.N == 0:
@@ -1331,14 +1339,17 @@ class ClusterSim:
 
         def process_job_run(run_events):
             """One contiguous run of *fresh* done/oom events inside a
-            same-time batch: stage wastage and compacted retries exactly
-            like the pre-churn whole-batch path (no membership change can
-            occur inside a run, so the staging stays decision-safe), then
-            process the events one at a time."""
+            same-time batch.  No membership change can occur inside a
+            run, so everything that does not depend on placements —
+            wastage against the *pre-retry* plans, the compacted retry
+            re-plans, attempts, DAG releases, parking — is done for the
+            whole run first; then one :meth:`AdmissionState.drain_run`
+            call drains after each event in order, and the placements'
+            event pushes are replayed in the per-event loop's order, so
+            every ``seq`` number (the heap's tie order) is as if the
+            events had been processed one at a time."""
             nonlocal retries, unschedulable, doomed, finished
             nonlocal area_used, done_at
-            # Stage wastage for the run against the *pre-retry* plans
-            # (compacted multi-row span arithmetic).
             done_idx = [ev[4] for ev in run_events if ev[2] == "done"]
             oom_idx = [ev[4] for ev in run_events if ev[2] == "oom"]
             w_done: Dict[int, float] = {}
@@ -1369,16 +1380,21 @@ class ClusterSim:
                 else:
                     stage_retries(retry_set)
                 # NOTE: the admission state keeps each lane's OLD plan
-                # until that lane's kill event is processed below — while
-                # an OOMing job is still resident, the node's residual
-                # must be computed against the envelope it was admitted
-                # with, not the staged re-plan.
+                # until that lane's kill event — while an OOMing job is
+                # still resident, the node's residual must be computed
+                # against the envelope it was admitted with, not the
+                # staged re-plan (``replan`` below takes effect as the
+                # lane leaves its node).
             retryable = set(retry_set)
 
-            # Process the run one event at a time — identical admission
-            # interleaving to the per-event loop.
+            # Per event: (node row, lane, lanes joining the queue, staged
+            # re-plan) for the drain, and the children released later.
+            steps = []
+            later: List[List[int]] = []
             for (t_, _, kind, nid, ji, _) in run_events:
-                adm.release(active_nids.index(nid), ji)
+                joins: List[int] = []
+                replan = None
+                arrive: List[int] = []
                 if kind == "done":
                     wasted[ji] += (w_done[ji] - summem[ji]) * dts[ji]
                     area_used += summem[ji] * dts[ji]
@@ -1386,29 +1402,55 @@ class ClusterSim:
                     finished += 1
                     if frontier is not None:  # dependency-release
                         for c in frontier.release(ji):
-                            if release[c] > t_:
-                                heapq.heappush(
-                                    events, (float(release[c]), next(seq),
-                                             "arrive", -1, c, 0))
-                            else:
-                                queue.append(c)
+                            (arrive if release[c] > t_ else joins).append(c)
                 else:  # OOM kill
                     wasted[ji] += w_oom[ji] * dts[ji]
                     attempts[ji] += 1
                     retries += 1
                     if ji in retryable:
-                        # The lane left its node: its staged re-plan may
-                        # now become visible to admission.
-                        adm.update_lane(ji, starts[ji], peaks[ji],
-                                        need[ji])
-                        queue.append(ji)
+                        replan = (starts[ji], peaks[ji], need[ji])
+                        joins.append(ji)
                     else:
                         unschedulable += 1
                         if frontier is not None:  # descendants blocked
                             d = frontier.doom(ji)
                             doomed += d
                             unschedulable += d
-                try_admit(t_)
+                steps.append((active_nids.index(nid), ji, joins, replan))
+                later.append(arrive)
+            t = run_events[0][0]
+
+            def push_arrivals(arrive):
+                for c in arrive:
+                    heapq.heappush(events, (float(release[c]), next(seq),
+                                            "arrive", -1, c, 0))
+
+            if not device_drain:
+                for (ni, ji, joins, replan), arrive in zip(steps, later):
+                    adm.release(ni, ji)
+                    if replan is not None:
+                        adm.update_lane(ji, *replan)
+                    push_arrivals(arrive)
+                    for c in joins:
+                        queue.append(c)
+                    try_admit(t)
+                return
+            # The park sweep against the run's unchanged capacities: the
+            # queue, then each event's joins, in queue order.
+            ids = park_misfits(queue.ids(), t)
+            for i, (ni, ji, joins, replan) in enumerate(steps):
+                for c in joins:
+                    queue.append(c)
+                joins = park_misfits(np.asarray(joins, np.int64), t)
+                steps[i] = (ni, ji, joins.tolist(), replan)
+            adm.sync_now(t)
+            placed = adm.drain_run(t, ids, steps)
+            for arrive, got in zip(later, placed):
+                push_arrivals(arrive)
+                if got:
+                    queue.remove_many([ji for ji, _ in got])
+                    for ji, ni in got:
+                        place_record(t, ni, ji)
 
         def process_leave(t: float, nid: int):
             """Node death: drop the admission row (validity-mask entries

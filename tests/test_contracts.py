@@ -141,6 +141,31 @@ class TestOneProgramPerDrain:
             jnp.float64(1.5)
         assert "convert_element_type" in prims
 
+    @staticmethod
+    def _scripted_run(seed=8, caps=(40.0, 20.0, 36.0)):
+        """Residents placed at t=0, a standing queue, then a same-instant
+        run of 4 completions at t=7 (:meth:`AdmissionState.drain_run`)."""
+        adm = _mk_state("fused", caps=caps)
+        lanes = _mk_lanes(adm, np.random.default_rng(seed), 14)
+        placed = adm.drain(0.0, lanes)
+        got = {ji for ji, _ in placed}
+        queue = [ji for ji in lanes if ji not in got]
+        return adm, queue, [(ni, ji, [], None) for ji, ni in placed[:4]]
+
+    def test_same_instant_run_is_one_program(self):
+        """A run of 4 completions drains in ONE program: one
+        ``admission.drain`` dispatch, one ``admission.drain.upload`` and
+        no compile once its shapes are warm."""
+        adm, queue, events = self._scripted_run()
+        adm.drain_run(7.0, queue, events)  # warm the run's shapes
+        adm, queue, events = self._scripted_run()
+        assert len(events) == 4 and queue
+        with dispatch_budget(compiles=0) as b:
+            placed = adm.drain_run(7.0, queue, events)
+        assert b.tag_counts["admission.drain"] == 1
+        assert b.tag_counts["admission.drain.upload"] == 1
+        assert len(placed) == 4 and any(placed)
+
     def test_different_caps_same_program(self):
         """Capacity values are operands, not shapes: once each scripted
         config has warmed its buckets, fresh states under either config

@@ -41,7 +41,12 @@ from repro.sched import (
     OffsetCandidate,
 )
 from repro.analysis.contracts import dispatch_budget
-from repro.sched.admission import AdmissionState, _drain_pack, _drain_unpack
+from repro.sched.admission import (
+    RUN_STEPS,
+    AdmissionState,
+    _drain_pack,
+    _drain_unpack,
+)
 
 from test_admission_fused import (
     _assert_same,
@@ -165,27 +170,37 @@ class TestPackedOperands:
     def test_pack_unpack_bitwise(self, now, tol):
         import jax
         rng = np.random.default_rng(31)
-        B, N, npad, rmax, nq, Q = 8192, 5, 8, 16, 37, 64
+        B, N, npad, rmax, nq, Q, K = 8192, 5, 8, 16, 37, 64, 4
+        S = RUN_STEPS
         caps = np.full(npad, -1e30)
         caps[:N] = rng.uniform(16.0, 128.0, N)
         node_valid = np.arange(npad) < N
         run_idx = rng.integers(0, B, (npad, rmax)).astype(np.int32)
         run_idx[0, 0] = B - 1
         run_valid = rng.uniform(size=(npad, rmax)) < 0.5
-        used_now = np.where(node_valid, rng.uniform(0.0, 64.0, npad), 0.0)
+        used_now = np.where(node_valid, rng.uniform(0.0, 64.0, (S, npad)),
+                            np.nan)
         q_idx = np.zeros(Q, np.int32)
         q_idx[:nq] = rng.integers(0, B + 1, nq)
         q_idx[nq - 1] = B
-        q_valid = rng.uniform(size=Q) < 0.5
+        q_from = np.full(Q, S, np.int32)
+        q_from[:nq] = rng.integers(0, S, nq)
+        leave_node = rng.integers(0, npad + 1, S).astype(np.int32)
+        leave_slot = rng.integers(0, rmax, S).astype(np.int32)
+        old_starts = rng.uniform(0.0, 1e3, (S, K))
+        old_starts[0, -1] = 1e30
+        old_peaks = rng.uniform(0.0, 128.0, (S, K))
         want = (caps, node_valid, run_idx, run_valid, used_now, q_idx,
-                q_valid, np.float64(now), np.float64(tol))
+                q_from, leave_node, leave_slot, old_starts, old_peaks,
+                np.int32(S - 3), np.float64(now), np.float64(tol))
         packed = _drain_pack(*want)
         assert packed.dtype == np.float64
-        assert packed.shape == (3 * npad + 2 * npad * rmax + 2 * Q + 2,)
-        unpack = jax.jit(_drain_unpack, static_argnums=(1, 2, 3))
+        assert packed.shape == (2 * npad + 2 * npad * rmax + S * npad
+                                + 2 * Q + 2 * S + 2 * S * K + 3,)
+        unpack = jax.jit(_drain_unpack, static_argnums=(1, 2, 3, 4))
         with jax.enable_x64(True):
             got = jax.device_get(
-                unpack(jax.device_put(packed), npad, rmax, Q))
+                unpack(jax.device_put(packed), npad, rmax, Q, K))
         for w, g in zip(want, got):
             g = np.asarray(g)
             assert (g.dtype, g.shape) == (w.dtype, w.shape)
@@ -274,6 +289,162 @@ class TestAdmissionInstantFromHost:
         with pytest.raises(ValueError, match="start at 0"):
             adm.add_lanes(np.zeros((1, 2)), np.ones((1, 2)), np.ones((1, 4)),
                           np.linspace(1.0, 4.0, 4)[None])
+
+
+# ------------------------------------------------- a same-instant run
+def _run_case(backend, case, select, drain_cap=None):
+    """A standing queue on four nodes at ``t0``, then a same-instant run
+    of events at ``now``: residents leave in placement order, fresh lanes
+    join (``children``), retried residents re-join with another re-plan
+    (``retry``), the queue starts empty (``empty``), the run is longer
+    than one program call (``long``), instants carry fractions and lie a
+    whole number of samples from whole-number segment starts
+    (``fractional``), the queue is wider than ``drain_cap`` (``wide``,
+    which goes event by event).  Returns ``(adm, now, queue, events)``."""
+    from repro.core.envelope import alloc_at_packed
+    seed = {"retry": 1, "children": 2, "empty": 3, "long": 4,
+            "fractional": 5, "wide": 6}[case]
+    adm = _mk_state(backend, caps=(96.0, 64.0, 120.0, 80.0))
+    if drain_cap is not None:
+        adm.DRAIN_CAP = drain_cap
+    lanes = [int(x) for x in _mk_lanes(adm, np.random.default_rng(seed), 90)]
+    t0, now = (0.5, 12.5) if case == "fractional" else (0.0, 12.0)
+    if case == "fractional":
+        for ji in lanes:
+            st = np.where(adm.starts[ji] < 1e29, np.round(adm.starts[ji]),
+                          adm.starts[ji])
+            adm.update_lane(ji, st, adm.peaks[ji], alloc_at_packed(
+                st[None], adm.peaks[ji][None], adm.grid[ji][None])[0])
+    pre, children = lanes[:55], lanes[55:]
+    residents = adm.drain(t0, pre, select=select)
+    placed0 = {ji for ji, _ in residents}
+    queue = [] if case == "empty" else [ji for ji in pre
+                                        if ji not in placed0]
+    if case == "retry":  # a short queue, so re-queued retries can place
+        queue, children = queue[:3], []
+    n = RUN_STEPS + 4 if case == "long" else 7
+    # Retried mid-run: the first two residents after the first event
+    # whose envelope has more than one segment.
+    multi = [k for k, (ji, _) in enumerate(residents[:n])
+             if k and (adm.starts[ji] < 1e29).sum() > 1][:2]
+    events = []
+    for k, (ji, ni) in enumerate(residents[:n]):
+        joins = (children[2 * k:2 * k + 2]
+                 if case != "empty" or k >= 2 else [])
+        replan = None
+        if case == "retry" and k in multi:
+            # One re-plan moves the segment starts, the other the peaks.
+            first = k == multi[0]
+            st = np.where(adm.starts[ji] < 1e29, adm.starts[ji]
+                          * (0.1 if first else 1.0), adm.starts[ji])
+            peaks = adm.peaks[ji] * (1.0 if first else 0.3)
+            replan = (st, peaks, alloc_at_packed(
+                st[None], peaks[None], adm.grid[ji][None])[0])
+            joins = joins + [ji]
+        events.append((ni, ji, joins, replan))
+    return adm, now, queue, events
+
+
+def _one_by_one(adm, now, queue, events, select):
+    """The events drained one at a time, as the per-event loop did."""
+    out, q = [], list(queue)
+    for ni, ji, joins, replan in events:
+        adm.release(ni, ji)
+        if replan is not None:
+            adm.update_lane(ji, *replan)
+        q += joins
+        got = adm.drain(now, q, select=select) if q else []
+        out.append(got)
+        q = [x for x in q if x not in {lane for lane, _ in got}]
+    return out
+
+
+class TestDrainRun:
+    """``AdmissionState.drain_run`` folds a same-instant run of events
+    into one program call per ``RUN_STEPS`` events; its placements, their
+    order and the residents it leaves must be bitwise those of the same
+    events drained one by one, and the numpy host drain's."""
+
+    @pytest.mark.parametrize("select", ["first", "headroom"])
+    @pytest.mark.parametrize("case", ["retry", "children", "empty", "long",
+                                      "fractional", "wide"])
+    def test_matches_events_one_by_one(self, case, select):
+        cap = 8 if case == "wide" else None
+        adm, now, queue, events = _run_case("fused", case, select, cap)
+        d0 = adm.stats["drain_dispatches"]
+        with dispatch_budget() as b:
+            got = adm.drain_run(now, queue, events, select=select)
+        calls = adm.stats["drain_dispatches"] - d0
+        tags = b.tag_counts
+        ref, rnow, rqueue, revents = _run_case("fused", case, select, cap)
+        want = _one_by_one(ref, rnow, rqueue, revents, select)
+        host, hnow, hqueue, hevents = _run_case("numpy", case, select, cap)
+        assert got == want
+        assert host.drain_run(hnow, hqueue, hevents, select) == want
+        assert adm.running == ref.running == host.running
+        assert len(got) == len(events)
+        # Placements happen after the first event, not only at step 0.
+        assert sum(len(p) for p in got[1:]) > 0
+        if case == "wide":
+            assert calls == tags["admission.drain"] > 1
+        else:
+            assert calls == tags["admission.drain"] \
+                == tags["admission.drain.upload"] \
+                == -(-len(events) // RUN_STEPS)
+        if case == "empty":
+            assert got[0] == got[1] == []
+        if case == "fractional":
+            assert not adm._whole_times
+        if case == "retry":
+            retried = {ji for _, ji, _, rp in events if rp is not None}
+            assert retried & {ji for p in got for ji, _ in p}
+
+    @pytest.mark.parametrize("select", ["first", "headroom"])
+    @pytest.mark.parametrize("moved", ["starts", "peaks"])
+    def test_retried_resident_keeps_its_admitted_envelope(self, moved,
+                                                          select):
+        """One node of 10: X leaves at step 0, then R is retried at step
+        1 with a re-plan of 9 from ``rel`` 1 on (``moved``: a segment
+        start brought in, or the peaks raised).  Until it leaves, R
+        counts with the 2 it was admitted with, so the queued L (4)
+        places at step 0, not step 1."""
+        grid = np.linspace(0.0, 8.0, 8)
+        old = (np.asarray([0.0, 100.0]), np.asarray([2.0, 9.0])) \
+            if moved == "starts" else \
+            (np.asarray([0.0, 1e30]), np.asarray([2.0, 2.0]))
+        new = (np.asarray([0.0, 1.0]), np.asarray([2.0, 9.0])) \
+            if moved == "starts" else \
+            (np.asarray([0.0, 1e30]), np.asarray([9.0, 9.0]))
+        from repro.core.envelope import alloc_at_packed
+
+        def need(st, pk):
+            return alloc_at_packed(st[None], pk[None], grid[None])[0]
+
+        def build(backend):
+            adm = AdmissionState([10.0], K=2, G=8, backend=backend)
+            one = np.asarray([0.0, 1e30])
+            adm.add_lanes(np.stack([one, old[0], one]),
+                          np.stack([np.full(2, 5.0), old[1], np.full(2, 4.0)]),
+                          np.stack([need(one, np.full(2, 5.0)), need(*old),
+                                    need(one, np.full(2, 4.0))]),
+                          np.stack([grid] * 3))
+            adm.place(0, 0, 0.0)     # X
+            adm.place(0, 1, 0.0)     # R
+            return adm
+
+        events = [(0, 0, [], None), (0, 1, [1], (*new, need(*new)))]
+        want = [[(2, 0)], []]
+        assert _one_by_one(build("fused"), 3.0, [2], events, select) == want
+        for backend in ("numpy", "fused"):
+            got = build(backend).drain_run(3.0, [2], events, select=select)
+            assert got == want, backend
+
+    def test_steps_counted(self):
+        adm, now, queue, events = _run_case("fused", "long", "first")
+        s0 = dict(adm.stats)
+        adm.drain_run(now, queue, events)
+        assert adm.stats["drain_steps"] - s0["drain_steps"] == len(events)
+        assert adm.stats["drains"] - s0["drains"] == 2
 
 
 # ----------------------------------------------------------- engine level

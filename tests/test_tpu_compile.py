@@ -118,8 +118,11 @@ def _drain_args(shard_node, rep):
 
 def _packed_drain_args(sharding):
     """The unsharded drain's signature: the six lane buffers and the one
-    packed float64 operand vector (``admission._drain_pack``'s layout)."""
-    n_packed = 3 * N_ADM + 2 * N_ADM * R_ADM + 2 * Q_ADM + 2
+    packed float64 operand vector (``admission._drain_pack``'s layout,
+    with the run's per-step operands for ``RUN_STEPS`` steps)."""
+    S = admission.RUN_STEPS
+    n_packed = (2 * N_ADM + 2 * N_ADM * R_ADM + S * N_ADM + 2 * Q_ADM
+                + 2 * S + 2 * S * K_ADM + 3)
     return _drain_args(sharding, sharding)[:6] + (
         _spec((n_packed,), jnp.float64, sharding),)
 
@@ -131,9 +134,10 @@ def test_drain_compiles(one_chip, monkeypatch, select):
         kernel = admission._drain_kernel(True, select)
         compiled = kernel.lower(*_packed_drain_args(one_chip), npad=N_ADM,
                                 rmax=R_ADM, Q=Q_ADM).compile()
-    # One int32 vector back (out_lane | out_node | count) and admit_t.
+    # One int32 vector back (out_lane | out_node | out_step | count) and
+    # admit_t.
     out, admit = compiled.out_info
-    assert (out.shape, out.dtype) == ((2 * Q_ADM + 1,), jnp.int32)
+    assert (out.shape, out.dtype) == ((3 * Q_ADM + 1,), jnp.int32)
     assert admit.shape == (B_ADM,)
 
 
